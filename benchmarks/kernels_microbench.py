@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops, ref
+from repro.launch.mesh import make_mesh
 
 N = 1 << 20
 
@@ -474,7 +475,7 @@ def shard_local_microbench() -> dict:
             "shard-local bench needs >= 2 devices "
             "(XLA_FLAGS=--xla_force_host_platform_device_count=2)")
     W, n_shards = 4, 2
-    mesh = jax.make_mesh((1, n_shards), ("data", "model"))
+    mesh = make_mesh((1, n_shards), ("data", "model"))
     model = get_model("granite-8b", reduced=True)
     theta, lam, h = _transformer_trees(W)
     dims = model_shard_dims(theta, model.cfg, mesh, multi_pod=False)
@@ -565,7 +566,7 @@ def sketched_microbench() -> dict:
         raise RuntimeError(
             "sketched bench needs >= 4 devices "
             "(XLA_FLAGS=--xla_force_host_platform_device_count=4)")
-    mesh = jax.make_mesh((1, 2, 2), ("data", "fsdp", "model"))
+    mesh = make_mesh((1, 2, 2), ("data", "fsdp", "model"))
     model = get_model("granite-8b", reduced=True)
     W, B, T = 4, 2, 16
     key = jax.random.PRNGKey(0)
@@ -953,35 +954,6 @@ def scaleup_microbench() -> dict:
     }
 
 
-def device_microbench() -> dict:
-    """Opt-in real-accelerator lane (closes ROADMAP item 1's leftover):
-    ``REPRO_BENCH_DEVICE=gpu|tpu`` runs the pallas population step and the
-    fused OTA round autotuners on the actual device; unset — or a platform
-    mismatch (the usual CPU CI) — returns a clean skip marker instead of
-    interpreting pallas kernels for hours."""
-    import os
-    want = os.environ.get("REPRO_BENCH_DEVICE", "").lower()
-    plat = jax.default_backend()
-    if not want:
-        return {"skipped": True, "platform": plat,
-                "reason": "REPRO_BENCH_DEVICE unset (opt-in lane)"}
-    if plat != want:
-        return {"skipped": True, "platform": plat,
-                "reason": f"REPRO_BENCH_DEVICE={want} but jax platform "
-                          f"is {plat}"}
-    from repro.core.transport import autotune_ota_round
-    from repro.phy import autotune_population_step
-    pop = autotune_population_step(1 << 20, backend="pallas")
-    rnd = autotune_ota_round(256, 1 << 16, backend="pallas")
-    return {
-        "skipped": False,
-        "platform": plat,
-        "population_step_1M": pop,
-        "ota_round_256x65536": rnd,
-        "optimised_metric": "population_step_1M.best.us",
-    }
-
-
 # ---------------------------------------------------------------------------
 # flash attention forward + backward (custom_vjp) dispatch counts
 # ---------------------------------------------------------------------------
@@ -1133,13 +1105,6 @@ def main() -> None:
                          "the 1-launch freq-flat Scenario.step pin")
     ap.add_argument("--out-scaleup", default="BENCH_scaleup_micro.json",
                     help="where --scaleup writes its JSON")
-    ap.add_argument("--device-bench", action="store_true",
-                    help="opt-in real-accelerator lane: honours "
-                         "REPRO_BENCH_DEVICE=gpu|tpu, self-skips cleanly "
-                         "on CPU / unset (no file written when skipped)")
-    ap.add_argument("--out-device-bench", default="BENCH_device.json",
-                    help="where --device-bench writes its JSON (skipped "
-                         "runs print the skip marker and write nothing)")
     args = ap.parse_args()
     if args.shard_local or args.sketched:
         # must happen before jax's first backend init (the import above is
@@ -1152,8 +1117,7 @@ def main() -> None:
     derived = {}
     if not (args.packed_only or args.attn_bwd or args.phy
             or args.shard_local or args.fused_round or args.faults
-            or args.sketched or args.obs or args.scaleup
-            or args.device_bench):
+            or args.sketched or args.obs or args.scaleup):
         derived = {"kernels": microbench(),
                    "transport": transport_microbench()}
     out = dict(derived)
@@ -1177,8 +1141,6 @@ def main() -> None:
         out["obs"] = obs_microbench()
     if args.scaleup:
         out["scaleup"] = scaleup_microbench()
-    if args.device_bench:
-        out["device"] = device_microbench()
     text = json.dumps(out, indent=2, default=str)
     print(text)
     if args.out and derived:
@@ -1214,9 +1176,6 @@ def main() -> None:
     if args.scaleup:
         with open(args.out_scaleup, "w") as f:
             f.write(json.dumps(out["scaleup"], indent=2, default=str) + "\n")
-    if args.device_bench and not out["device"].get("skipped"):
-        with open(args.out_device_bench, "w") as f:
-            f.write(json.dumps(out["device"], indent=2, default=str) + "\n")
 
 
 if __name__ == "__main__":

@@ -62,6 +62,8 @@ def resolve_backend(backend: Optional[str] = None) -> str:
 
 
 def _interpret() -> bool:
+    """Pallas kernels run interpreted off the TPU (the CPU tests), and
+    compile to Mosaic on it."""
     return jax.default_backend() != "tpu"
 
 
@@ -235,14 +237,19 @@ def dual_update(lam: Complex, h: Complex, theta: Array, Theta: Array,
     if resolve_backend(backend) == "pallas":
         from repro.kernels import admm_update as _k
         shape = lam.re.shape
-        th = jnp.broadcast_to(_f32(theta), shape)
-        Th = jnp.broadcast_to(_f32(Theta), shape)
-        nz = jnp.broadcast_to(jnp.asarray(noise_re, jnp.float32), shape)
+        # the kernel reads Θ once per worker: no (W, ...) broadcast in HBM
+        W = shape[0] if len(shape) > 1 and jnp.shape(Theta) == shape[1:] \
+            else 1
+        if W == 1:
+            Theta = jnp.broadcast_to(Theta, shape)
+        flat = lambda x: x.reshape(W, -1)
+        nz = None
+        if not (isinstance(noise_re, (int, float)) and noise_re == 0.0):
+            nz = flat(jnp.broadcast_to(jnp.asarray(noise_re, jnp.float32),
+                                       shape))
         ore, oim = _k.admm_dual_update(
-            lam.re.reshape(-1), lam.im.reshape(-1),
-            h.re.reshape(-1), h.im.reshape(-1),
-            th.reshape(-1), Th.reshape(-1), float(rho), nz.reshape(-1),
-            interpret=_interpret())
+            flat(lam.re), flat(lam.im), flat(h.re), flat(h.im), flat(theta),
+            Theta.reshape(-1), float(rho), nz, interpret=_interpret())
         return Complex(ore.reshape(shape), oim.reshape(shape))
     r = _f32(theta) - _f32(Theta)
     return Complex(lam.re + rho * (h.re * r - noise_re),

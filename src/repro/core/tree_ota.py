@@ -576,7 +576,6 @@ def unpack_cplx_shard_local(sspec: ShardPackSpec, buf: Complex, mesh,
     the trainer reads λ/h slice-views for the penalty gradient without ever
     materialising a replicated (W, D) buffer.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     daxes = _mesh_data_axes(mesh, model_axis, fsdp_axis)
@@ -602,9 +601,9 @@ def unpack_cplx_shard_local(sspec: ShardPackSpec, buf: Complex, mesh,
 
     out_specs = _shard_theta_specs(sspec, wentry, model_axis,
                                    worker_dim=True, fsdp_axis=fsdp_axis)
-    return shard_map(body, mesh=mesh,
-                     in_specs=(P(wentry, _axes_entry(saxes)),),
-                     out_specs=out_specs, check_rep=False)(buf)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(wentry, _axes_entry(saxes)),),
+                         out_specs=out_specs, check_vma=False)(buf)
 
 
 def ota_tree_round_shard_local(theta: PyTree, lam_p: Complex, h_p: Complex,
@@ -674,7 +673,6 @@ def ota_tree_round_shard_local(theta: PyTree, lam_p: Complex, h_p: Complex,
 
     Returns ``(Theta_tree_f32, lam_new_packed, metrics)``.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     rho = acfg.rho
@@ -921,9 +919,9 @@ def ota_tree_round_shard_local(theta: PyTree, lam_p: Complex, h_p: Complex,
         out_specs += [P(), P()]
         if want_energy_out:
             out_specs.append(P(wentry))
-    outs = shard_map(
+    outs = jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=tuple(out_specs),
-        check_rep=False)(
+        check_vma=False)(
         theta, lam_p, h_p, key,
         mask if has_mask else dummy,
         h_tx_p if has_htx else dummy,

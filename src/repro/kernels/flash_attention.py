@@ -14,9 +14,12 @@ innermost; the (m, l, acc) online-softmax state lives in VMEM scratch across
 KV steps.  Causal masking by absolute indices; fully-masked KV tiles skip
 the matmuls via ``pl.when``.  Besides the output ``o`` the kernel emits the
 per-row log-sum-exp residual ``lse = m + log(l)`` — ONE extra f32
-``(B, H, S)`` plane, the only thing the backward pass needs beyond the
+``(B, H, S, 1)`` plane, the only thing the backward pass needs beyond the
 primal inputs (the (S,S) probability tensor is never materialised in either
-pass).
+pass).  Row statistics (lse, δ, and the m/l scratch) are ``(rows, 1)``
+columns: a ``(bq, 1)`` block is tile-aligned for Mosaic (a trailing dim
+equal to the array's) and broadcasts against the ``(bq, bk)`` scores
+without a relayout.
 
 Backward (registered via :func:`jax.custom_vjp`): two kernels that
 recompute the probability block ``p = exp(s − lse)`` from the residuals:
@@ -27,7 +30,7 @@ recompute the probability block ``p = exp(s − lse)`` from the residuals:
   block, accumulating ``dv += pᵀ·do`` and ``dk += dsᵀ·q · scale``.
 
 Both skip fully-masked causal tiles with the same ``pl.when`` predicate as
-the forward.  ``δ = Σ_d do ∘ o`` (another (B,H,S) f32 plane) is computed
+the forward.  ``δ = Σ_d do ∘ o`` (another (B,H,S,1) f32 plane) is computed
 once outside the kernels.  Forward-mode AD (``jax.jvp``) is explicitly
 unsupported — JAX raises a clean ``TypeError`` for custom_vjp functions
 instead of the historical ``_pallas_call_jvp_rule`` AssertionError.
@@ -84,12 +87,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
                                 preferred_element_type=jnp.float32) * scale
         if causal:
             s = jnp.where(_causal_mask(qi, ki, bq, bk, t_limit), s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_ref[...]                            # (bq, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
@@ -103,7 +106,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
     @pl.when(ki == n_k - 1)
     def _finish():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
         lse_ref[0, 0] = m_ref[...] + jnp.log(l)
 
 
@@ -125,7 +128,8 @@ def _pad_qkv(q: Array, k: Array, v: Array, causal: bool, bq: int, bk: int):
 def _flash_forward(q: Array, k: Array, v: Array, *, causal: bool,
                    scale: float, block_q: int, block_k: int,
                    interpret: bool) -> Tuple[Array, Array]:
-    """Forward kernel launch.  Returns (o, lse), both sliced to S."""
+    """Forward kernel launch.  Returns (o, lse), both sliced to S; lse is
+    ``(B, H, S, 1)``."""
     B, H, S, hd = q.shape
     T = k.shape[2]
     bq = min(block_q, S)
@@ -146,15 +150,15 @@ def _flash_forward(q: Array, k: Array, v: Array, *, causal: bool,
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bq, hd), lambda b, h, qi, ki: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, qi, ki: (b, h, qi)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, qi, ki: (b, h, qi, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Sp, hd), q.dtype),
-            jax.ShapeDtypeStruct((B, H, Sp), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, Sp, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
         interpret=interpret,
@@ -181,16 +185,16 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         k = k_ref[0, 0].astype(jnp.float32)            # (bk, hd)
         v = v_ref[0, 0].astype(jnp.float32)
         do = do_ref[0, 0].astype(jnp.float32)          # (bq, hd)
-        lse = lse_ref[0, 0]                            # (bq,)
-        delta = delta_ref[0, 0]                        # (bq,)
+        lse = lse_ref[0, 0]                            # (bq, 1)
+        delta = delta_ref[0, 0]                        # (bq, 1)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if causal:
             s = jnp.where(_causal_mask(qi, ki, bq, bk, t_limit), s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])                  # masked entries -> 0
+        p = jnp.exp(s - lse)                  # masked entries -> 0
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None])
+        ds = p * (dp - delta)
         dq_acc[...] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
@@ -221,19 +225,19 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
         k = k_ref[0, 0].astype(jnp.float32)            # (bk, hd)
         v = v_ref[0, 0].astype(jnp.float32)
         do = do_ref[0, 0].astype(jnp.float32)          # (bq, hd)
-        lse = lse_ref[0, 0]                            # (bq,)
-        delta = delta_ref[0, 0]                        # (bq,)
+        lse = lse_ref[0, 0]                            # (bq, 1)
+        delta = delta_ref[0, 0]                        # (bq, 1)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if causal:
             s = jnp.where(_causal_mask(qi, ki, bq, bk, t_limit), s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])                  # (bq, bk)
+        p = jnp.exp(s - lse)                  # (bq, bk)
         dv_acc[...] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)        # pᵀ·do  (bk, hd)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None])
+        ds = p * (dp - delta)
         dk_acc[...] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # dsᵀ·q (bk, hd)
@@ -262,15 +266,16 @@ def _flash_backward(q: Array, k: Array, v: Array, o: Array, lse: Array,
     dop = jnp.pad(do, ((0, 0), (0, 0), (0, Sp - S), (0, 0)))
     # δ = Σ_d do ∘ o per row (f32): with do/δ zero on padded rows, those
     # rows contribute exactly 0 to every cotangent, so lse can pad with 0.
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    lsep = jnp.pad(lse, ((0, 0), (0, 0), (0, Sp - S)))
-    deltap = jnp.pad(delta, ((0, 0), (0, 0), (0, Sp - S)))
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
+                    keepdims=True)
+    lsep = jnp.pad(lse, ((0, 0), (0, 0), (0, Sp - S), (0, 0)))
+    deltap = jnp.pad(delta, ((0, 0), (0, 0), (0, Sp - S), (0, 0)))
     n_q = Sp // bq
     n_k = Tp // bk
 
     q_spec = pl.BlockSpec((1, 1, bq, hd), lambda b, h, qi, ki: (b, h, qi, 0))
     k_spec = pl.BlockSpec((1, 1, bk, hd), lambda b, h, qi, ki: (b, h, ki, 0))
-    r_spec = pl.BlockSpec((1, 1, bq), lambda b, h, qi, ki: (b, h, qi))
+    r_spec = pl.BlockSpec((1, 1, bq, 1), lambda b, h, qi, ki: (b, h, qi, 0))
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=float(scale), causal=causal,
@@ -287,7 +292,7 @@ def _flash_backward(q: Array, k: Array, v: Array, o: Array, lse: Array,
     # KV-major grid: program_id(2) walks KV tiles, Q tiles stream innermost
     qT_spec = pl.BlockSpec((1, 1, bq, hd), lambda b, h, ki, qi: (b, h, qi, 0))
     kT_spec = pl.BlockSpec((1, 1, bk, hd), lambda b, h, ki, qi: (b, h, ki, 0))
-    rT_spec = pl.BlockSpec((1, 1, bq), lambda b, h, ki, qi: (b, h, qi))
+    rT_spec = pl.BlockSpec((1, 1, bq, 1), lambda b, h, ki, qi: (b, h, qi, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=float(scale), causal=causal,
                           bq=bq, bk=bk, n_q=n_q,
@@ -343,8 +348,8 @@ def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = True,
 
     Differentiable: ``jax.grad``/``jax.vjp`` route through the Pallas
     backward kernels above (cotangents returned in the primal dtypes, f32
-    accumulation).  Residual cost beyond the primals: one f32 ``(B, H, S)``
-    log-sum-exp plane saved by the forward.
+    accumulation).  Residual cost beyond the primals: one f32
+    ``(B, H, S, 1)`` log-sum-exp plane saved by the forward.
     """
     hd = q.shape[-1]
     scale = hd ** -0.5 if scale is None else float(scale)

@@ -7,12 +7,15 @@ the sequence in VMEM-resident tiles, carrying the (1, bd) recurrence state in
 scratch across sequential grid steps — HBM traffic is exactly one read of
 (a, b) and one write of h.
 
-Grid: (B, D/bd, S/bs) — the sequence dimension is innermost, so for a fixed
-(batch, channel-tile) the S-tiles execute in order and the carry is live in
-VMEM the whole time.  Within a tile the recurrence closes with an associative
-scan (log-depth on the VPU) plus a cumprod-weighted carry injection:
+Grid: (B, D/bd, S/bs) — the sequence dimension is innermost (and
+``"arbitrary"``), so for a fixed (batch, channel-tile) the S-tiles execute
+in order and the carry is live in VMEM the whole time.  Within a tile the
+recurrence walks the rows in order, one ``(1, bd)`` lane row per step:
 
-    h_tile = assoc_scan(a, b) + cumprod(a) * carry
+    h_t = a_t * h_{t-1} + b_t,   h_{-1} = carry
+
+Mosaic lowers neither ``associative_scan`` nor ``cumprod`` along sublanes,
+and row-wise steps are exactly the reference recurrence.
 
 Differentiable via :func:`jax.custom_vjp`: the cotangent recurrence
 ``g_t = dh_t + a_{t+1} g_{t+1}`` is itself a linear scan run in reverse, so
@@ -37,24 +40,18 @@ DEFAULT_BS = 256   # sequence tile
 DEFAULT_BD = 128   # channel tile (lane width)
 
 
-def _scan_kernel(a_ref, b_ref, o_ref, carry_ref):
+def _scan_kernel(a_ref, b_ref, o_ref, carry_ref, *, block_s: int):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         carry_ref[...] = jnp.zeros_like(carry_ref)
 
-    a = a_ref[0]                       # (bs, bd)
-    b = b_ref[0]
+    def step(t, h):                    # h: (1, bd)
+        row = pl.ds(t, 1)
+        h = a_ref[0, row, :] * h + b_ref[0, row, :]
+        o_ref[0, row, :] = h
+        return h
 
-    def combine(e1, e2):
-        a1, b1 = e1
-        a2, b2 = e2
-        return a2 * a1, a2 * b1 + b2
-
-    _, h = jax.lax.associative_scan(combine, (a, b), axis=0)
-    cum_a = jnp.cumprod(a, axis=0)
-    h = h + cum_a * carry_ref[...][None, :]
-    o_ref[0] = h
-    carry_ref[...] = h[-1]
+    carry_ref[...] = jax.lax.fori_loop(0, block_s, step, carry_ref[...])
 
 
 def _scan_launch(a: Array, b: Array, *, block_s: int, block_d: int,
@@ -72,12 +69,14 @@ def _scan_launch(a: Array, b: Array, *, block_s: int, block_d: int,
     grid = (B, Dp // block_d, Sp // block_s)
     spec = pl.BlockSpec((1, block_s, block_d), lambda bi, di, si: (bi, si, di))
     out = pl.pallas_call(
-        _scan_kernel,
+        functools.partial(_scan_kernel, block_s=block_s),
         grid=grid,
         in_specs=[spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((B, Sp, Dp), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block_d,), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, block_d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(ap, bp)
     return out[:, :S, :D]

@@ -1,8 +1,8 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in ``interpret=True`` mode — the
-kernel body runs as traced JAX ops, validating the exact tiling/index logic
-that runs on TPU.  On a TPU backend the same calls compile to Mosaic.
+Off the TPU (the CPU test runs) the kernels execute in ``interpret=True``
+mode — the kernel body runs as traced JAX ops, validating the tiling/index
+logic.  On a TPU backend the same calls compile to Mosaic.
 """
 from __future__ import annotations
 
@@ -12,15 +12,12 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.transport import _interpret
 from repro.kernels import admm_update as _admm
 from repro.kernels import linear_scan as _scan
 from repro.kernels import ota as _ota
 
 Array = jax.Array
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("rho",))

@@ -25,22 +25,54 @@ Array = jax.Array
 
 LANE = 1024               # 8 sublanes x 128 lanes
 DEFAULT_BLOCK_ROWS = 256  # 256*1024*4B = 1 MiB per f32 operand tile
+MAX_BLOCK_COLS = 1024
+
+#: VMEM the kernels may fill with their pipelined tiles: under the 16 MiB
+#: scoped-VMEM default of v5e (the smallest of the TPU generations), with
+#: room left for the compiler's own scratch
+VMEM_TILE_BUDGET = 12 * 2 ** 20
 
 
-def _block_rows(block_rows: Optional[int]) -> int:
-    """Resolve the row-tile knob: explicit arg wins, else the live
-    ``REPRO_OTA_BLOCK_ROWS`` env read (``optflags.ota_block_rows``)."""
+def _block_rows(block_rows: Optional[int], n_planes: int) -> int:
+    """Row tile of a flat elementwise kernel: explicit arg, else
+    ``REPRO_OTA_BLOCK_ROWS``, else the most rows (a multiple of 8, at most
+    :data:`DEFAULT_BLOCK_ROWS`) whose double-buffered ``(rows, LANE)``
+    tiles of the launch's ``n_planes`` operands and results fit
+    :data:`VMEM_TILE_BUDGET`."""
     if block_rows is not None:
         return block_rows
     from repro import optflags
-    return optflags.ota_block_rows()
+    env = optflags.ota_block_rows()
+    if env is not None:
+        return env
+    per_row = 2 * n_planes * LANE * 4
+    return max(8, min(DEFAULT_BLOCK_ROWS,
+                      VMEM_TILE_BUDGET // per_row // 8 * 8))
 
 
-def _block_cols(block_cols: Optional[int]) -> int:
+def _block_cols(block_cols: Optional[int], n_workers: int,
+                n_planes: int) -> int:
+    """Column tile of a worker-grid kernel: explicit arg, else
+    ``REPRO_OTA_BLOCK_COLS``, else the widest multiple of 128 lanes (at
+    most :data:`MAX_BLOCK_COLS`) whose working set fits
+    :data:`VMEM_TILE_BUDGET`.
+
+    ``n_planes`` counts the ``(W, block_cols)`` operands and results of the
+    launch.  Each is double-buffered by the pipeline, and the body holds
+    about as many ``(W, block_cols)`` temporaries again, so the working set
+    is ``3 · n_planes · W₈ · block_cols · 4`` bytes, ``W₈`` being ``W``
+    rounded up to the 8 sublanes of a vreg.
+    """
     if block_cols is not None:
         return block_cols
     from repro import optflags
-    return optflags.ota_block_cols()
+    env = optflags.ota_block_cols()
+    if env is not None:
+        return env
+    w8 = -(-n_workers // 8) * 8
+    per_col = 3 * n_planes * w8 * 4
+    return max(128, min(MAX_BLOCK_COLS,
+                        VMEM_TILE_BUDGET // per_col // 128 * 128))
 
 
 def _mod_kernel(theta_ref, lre_ref, lim_ref, hre_ref, him_ref,
@@ -80,20 +112,31 @@ def _accumulate_kernel(yacc_ref, p2acc_ref, sre_ref, sim_ref, hre_ref,
 
 
 def _grid_spec(n_inputs: int, rows: int, block_rows: int):
-    grid = (rows // block_rows,)
-    spec = pl.BlockSpec((block_rows, LANE), lambda i: (i, 0))
+    """Row-blocked grid over ``(rows, LANE)`` planes.  The last block may
+    overhang ``rows``: Pallas reads it padded and drops the overhanging
+    writes, so no operand is copied to a block multiple."""
+    br = min(block_rows, rows)
+    grid = (-(-rows // br),)
+    spec = pl.BlockSpec((br, LANE), lambda i: (i, 0))
     return grid, [spec] * n_inputs, spec
 
 
+def _pad_lanes(x: Array, cols: int) -> Array:
+    """Zero-pad the last dim of ``x`` to ``cols``; no copy when it is that
+    wide already."""
+    pad = cols - x.shape[-1]
+    if not pad:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+
+
 def _pad_2d(x: Array, rows: int) -> Array:
-    flat = x.reshape(-1)
-    pad = rows * LANE - flat.shape[0]
-    return jnp.pad(flat, (0, pad)).reshape(rows, LANE)
+    """Flat ``x`` as ``(rows, LANE)``."""
+    return _pad_lanes(x.reshape(-1), rows * LANE).reshape(rows, LANE)
 
 
-def _rows_for(n: int, block_rows: int) -> int:
-    rows = -(-n // LANE)
-    return -(-rows // block_rows) * block_rows
+def _rows_for(n: int) -> int:
+    return -(-n // LANE)
 
 
 def ota_modulate(theta: Array, lam_re: Array, lam_im: Array, h_re: Array,
@@ -101,9 +144,9 @@ def ota_modulate(theta: Array, lam_re: Array, lam_im: Array, h_re: Array,
                  block_rows: Optional[int] = None,
                  interpret: bool = False) -> Tuple[Array, Array]:
     """Fused s = conj(h)·θ + conj(λ)/ρ over a flat parameter vector."""
-    block_rows = _block_rows(block_rows)
+    block_rows = _block_rows(block_rows, 7)
     n = theta.size
-    rows = _rows_for(n, block_rows)
+    rows = _rows_for(n)
     args = [_pad_2d(a.astype(jnp.float32), rows)
             for a in (theta, lam_re, lam_im, h_re, h_im)]
     grid, in_specs, out_spec = _grid_spec(5, rows, block_rows)
@@ -122,9 +165,9 @@ def ota_demodulate(y_re: Array, noise_re: Array, sumh2: Array,
                    inv_alpha: float, *, block_rows: Optional[int] = None,
                    interpret: bool = False) -> Array:
     """Fused Θ = (y_re + z_re/α) / max(Σ|h|², eps)."""
-    block_rows = _block_rows(block_rows)
+    block_rows = _block_rows(block_rows, 4)
     n = y_re.size
-    rows = _rows_for(n, block_rows)
+    rows = _rows_for(n)
     args = [_pad_2d(a.astype(jnp.float32), rows)
             for a in (y_re, noise_re, sumh2)]
     grid, in_specs, out_spec = _grid_spec(3, rows, block_rows)
@@ -146,26 +189,30 @@ def _scalar_spec():
 
 def ota_demodulate_dyn(y_re: Array, noise_re: Array, sumh2: Array,
                        inv_alpha: Array | float,
-                       *, block_rows: Optional[int] = None,
+                       *, block_cols: Optional[int] = None,
                        interpret: bool = False) -> Array:
     """Fused Θ = (y_re + z_re·inv_alpha) / max(Σ|h|², eps) with a *traced*
-    inv_alpha scalar (the power-control α is data-dependent per round)."""
-    block_rows = _block_rows(block_rows)
+    inv_alpha scalar (the power-control α is data-dependent per round).
+
+    The ``(d,)`` planes run as one ``(1, d)`` row on a column grid, the
+    layout the round's stats kernel emits ``y_re``/``Σ|h|²`` in, so no
+    operand is re-tiled in HBM on the way in."""
     n = y_re.size
-    rows = _rows_for(n, block_rows)
-    args = [_pad_2d(a.astype(jnp.float32), rows)
+    block_cols = _block_cols(block_cols, 1, 4)
+    cols = -(-n // block_cols) * block_cols
+    args = [_pad_lanes(a.astype(jnp.float32).reshape(1, n), cols)
             for a in (y_re, noise_re, sumh2)]
     ia = jnp.asarray(inv_alpha, jnp.float32).reshape(1)
-    grid, in_specs, out_spec = _grid_spec(3, rows, block_rows)
+    spec = pl.BlockSpec((1, block_cols), lambda i: (0, i))
     out = pl.pallas_call(
         _demod_dyn_kernel,
-        grid=grid,
-        in_specs=[_scalar_spec()] + in_specs,
-        out_specs=out_spec,
-        out_shape=jax.ShapeDtypeStruct((rows, LANE), jnp.float32),
+        grid=(cols // block_cols,),
+        in_specs=[_scalar_spec()] + [spec] * 3,
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct((1, cols), jnp.float32),
         interpret=interpret,
     )(ia, *args)
-    return out.reshape(-1)[:n]
+    return out[0, :n].reshape(y_re.shape)
 
 
 def ota_accumulate(y_re: Array, sumh2: Array, s_re: Array, s_im: Array,
@@ -181,9 +228,9 @@ def ota_accumulate(y_re: Array, sumh2: Array, s_re: Array, s_im: Array,
     superposition of the time-multiplexed (sketched) uplink, whose final
     demodulate then runs once per round (``ota_demodulate_dyn``).
     """
-    block_rows = _block_rows(block_rows)
+    block_rows = _block_rows(block_rows, 8)
     n = y_re.size
-    rows = _rows_for(n, block_rows)
+    rows = _rows_for(n)
     args = [_pad_2d(a.astype(jnp.float32), rows)
             for a in (y_re, sumh2, s_re, s_im, h_re, h_im)]
     grid, in_specs, out_spec = _grid_spec(6, rows, block_rows)
@@ -216,8 +263,8 @@ def ota_receive(s_re: Array, s_im: Array, h_re: Array, h_im: Array,
     shard-local round passes ``reduce_fn=None`` whenever the worker axis is
     local, so the whole receive stays one kernel per shard).
     """
-    block_cols = _block_cols(block_cols)
     W, n = s_re.shape
+    block_cols = _block_cols(block_cols, W, 4)
     cols = -(-n // block_cols) * block_cols
 
     def padw(x: Array) -> Array:

@@ -23,15 +23,16 @@ plane exactly once.  The kernels here do that:
   collapses into the same launch: modulate → mask → superpose → AWGN →
   matched filter → demodulate, worker planes to Θ in ONE kernel.
 
-Per-worker energies are emitted as per-grid-step partials of shape
-``(n_col_blocks, W)`` — each grid step owns one row, so no output block is
-revisited — and the wrapper reduces over the block axis.  That changes the
-summation *order* versus ``transport.worker_energy`` (a single (W, d) row
-sum), so energies/α agree to float tolerance, not bitwise; the noise-free
-Θ stays bitwise regardless (zero noise × any α).
+Per-worker energies accumulate in a resident ``(W, 1)`` output block that
+every grid step revisits, so the stats kernel's grid axis is sequential
+(``"arbitrary"``).  The column-block partial sums change the summation
+*order* versus ``transport.worker_energy`` (a single (W, d) row sum), so
+energies/α agree to float tolerance, not bitwise; the noise-free Θ stays
+bitwise regardless (zero noise × any α).
 
 Layout matches the kernel set: flat f32 planes on a column grid of
-``block_cols`` lanes; runtime scalars ride in SMEM.
+``block_cols`` lanes (sized from W by ``kernels/ota._block_cols`` so the
+working set fits VMEM); runtime scalars ride in SMEM.
 """
 from __future__ import annotations
 
@@ -43,8 +44,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import optflags
-from repro.kernels.ota import LANE
+from repro.kernels.ota import _block_cols
 
 Array = jax.Array
 
@@ -97,8 +97,13 @@ def _round_kernel(*refs, inv_rho: float, has_mask: bool, has_htx: bool,
 
     if not emit_theta:
         # per-worker energy of the UNMASKED signal (power control measures
-        # what the worker WOULD send; participation applies in min-α)
-        e_ref[...] = jnp.sum(sre * sre + sim * sim, axis=1)[None, :]
+        # what the worker WOULD send; participation applies in min-α),
+        # accumulated over the column blocks in the resident (W, 1) block
+        @pl.when(pl.program_id(0) == 0)
+        def _init():
+            e_ref[...] = jnp.zeros_like(e_ref)
+
+        e_ref[...] += jnp.sum(sre * sre + sim * sim, axis=1, keepdims=True)
 
     if has_mask:
         active = m_ref[...] != 0.0
@@ -121,12 +126,14 @@ def _round_call(theta, lam_re, lam_im, h_re, h_im, rho, *, mask, htx, chan,
                 noise_ia, block_cols, interpret):
     """Assemble specs/operands for the shared round kernel and launch it."""
     W, n = theta.shape
-    if block_cols is None:
-        block_cols = optflags.ota_block_cols()
-    cols = -(-n // block_cols) * block_cols
     emit_theta = noise_ia is not None
     has_mask, has_htx, has_chan = (mask is not None, htx is not None,
                                    chan is not None)
+    # (W, block_cols) planes of the launch: mask, the five round planes,
+    # CSI and innovations in, the stepped channel out
+    block_cols = _block_cols(block_cols, W,
+                             has_mask + 5 + 2 * has_htx + 4 * has_chan)
+    cols = -(-n // block_cols) * block_cols
 
     def padw(x: Array) -> Array:
         return jnp.pad(x.astype(jnp.float32), ((0, 0), (0, cols - n)))
@@ -134,7 +141,7 @@ def _round_call(theta, lam_re, lam_im, h_re, h_im, rho, *, mask, htx, chan,
     wspec = pl.BlockSpec((W, block_cols), lambda i: (0, i))
     mspec = pl.BlockSpec((W, block_cols), lambda i: (0, 0))
     rspec = pl.BlockSpec((1, block_cols), lambda i: (0, i))
-    espec = pl.BlockSpec((1, W), lambda i: (i, 0))
+    espec = pl.BlockSpec((W, 1), lambda i: (0, 0))
     wplane = jax.ShapeDtypeStruct((W, cols), jnp.float32)
     rplane = jax.ShapeDtypeStruct((1, cols), jnp.float32)
 
@@ -169,10 +176,9 @@ def _round_call(theta, lam_re, lam_im, h_re, h_im, rho, *, mask, htx, chan,
     if emit_theta:
         out_specs, out_shape = [rspec], [rplane]
     else:
-        n_blocks = cols // block_cols
         out_specs = [rspec, rspec, espec]
         out_shape = [rplane, rplane,
-                     jax.ShapeDtypeStruct((n_blocks, W), jnp.float32)]
+                     jax.ShapeDtypeStruct((W, 1), jnp.float32)]
     if has_chan:
         out_specs += [wspec, wspec]
         out_shape += [wplane, wplane]
@@ -186,6 +192,9 @@ def _round_call(theta, lam_re, lam_im, h_re, h_im, rho, *, mask, htx, chan,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
+        # the stats kernel revisits its energy block on every step
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel" if emit_theta else "arbitrary",)),
         interpret=interpret,
     )(*ops)
 
@@ -194,7 +203,7 @@ def _round_call(theta, lam_re, lam_im, h_re, h_im, rho, *, mask, htx, chan,
         res = (next(it).reshape(-1)[:n],)
     else:
         y, p2, e = next(it), next(it), next(it)
-        res = (y.reshape(-1)[:n], p2.reshape(-1)[:n], jnp.sum(e, axis=0))
+        res = (y.reshape(-1)[:n], p2.reshape(-1)[:n], e.reshape(W))
     if has_chan:
         res += (next(it)[:, :n], next(it)[:, :n])
     return res

@@ -30,8 +30,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 # one tiling scheme for the whole OTA/phy kernel set — a layout change in
 # kernels/ota.py (lane width, padding rule) must reach these kernels too
-from repro.kernels.ota import (DEFAULT_BLOCK_ROWS, LANE, _block_cols,
-                               _block_rows, _pad_2d, _rows_for)
+from repro.kernels.ota import (LANE, _block_cols, _block_rows, _grid_spec,
+                               _pad_2d, _rows_for)
 
 Array = jax.Array
 
@@ -62,16 +62,15 @@ def fading_step(h_re: Array, h_im: Array, w_re: Array, w_im: Array,
     are trace-time floats; ``redraw`` is a traced bool scalar (the coherence
     counter lives in jit-compiled round loops).
     """
-    block_rows = _block_rows(block_rows)
+    block_rows = _block_rows(block_rows, 6)
     n = h_re.size
-    rows = _rows_for(n, block_rows)
+    rows = _rows_for(n)
     args = [_pad_2d(a.astype(jnp.float32), rows)
             for a in (h_re, h_im, w_re, w_im)]
     params = jnp.stack([
         jnp.asarray(rho, jnp.float32), jnp.asarray(scale, jnp.float32),
         jnp.asarray(redraw, jnp.float32)])
-    grid = (rows // block_rows,)
-    spec = pl.BlockSpec((block_rows, LANE), lambda i: (i, 0))
+    grid, _, spec = _grid_spec(0, rows, block_rows)
     ore, oim = pl.pallas_call(
         _fading_step_kernel,
         grid=grid,
@@ -117,8 +116,8 @@ def ota_receive_masked(s_re: Array, s_im: Array, h_re: Array, h_im: Array,
     shard's launch unchanged — scenario participation is worker-level, so
     it is independent of how the packed axis is split.
     """
-    block_cols = _block_cols(block_cols)
     W, n = s_re.shape
+    block_cols = _block_cols(block_cols, W, 5)
     cols = -(-n // block_cols) * block_cols
 
     def padw(x: Array) -> Array:

@@ -32,7 +32,8 @@ from jax.experimental import pallas as pl
 
 # one tiling scheme for the whole OTA/phy kernel set — a layout change in
 # kernels/ota.py (lane width, padding rule) must reach this kernel too
-from repro.kernels.ota import LANE, _block_rows, _pad_2d, _rows_for
+from repro.kernels.ota import (LANE, _block_rows, _grid_spec, _pad_2d,
+                               _rows_for)
 from repro.kernels.phy_channel import _scalar_spec
 
 Array = jax.Array
@@ -100,9 +101,9 @@ def population_step(h_re: Array, h_im: Array, w_re: Array, w_im: Array,
     ``REPRO_OTA_BLOCK_ROWS`` knob (autotunable via
     ``phy.population.autotune_population_step``).
     """
-    block_rows = _block_rows(block_rows)
+    block_rows = _block_rows(block_rows, 20)
     n = h_re.size
-    rows = _rows_for(n, block_rows)
+    rows = _rows_for(n)
     planes = [_pad_2d(a.astype(jnp.float32), rows)
               for a in (h_re, h_im, w_re, w_im, pos_x, pos_y, dest_x, dest_y,
                         fresh_x, fresh_y, shadow, shadow_fresh)]
@@ -114,8 +115,7 @@ def population_step(h_re: Array, h_im: Array, w_re: Array, w_im: Array,
                         jnp.asarray(norm_d, jnp.float32),
                         jnp.asarray(pexp, jnp.float32),
                         jnp.asarray(shadow_redraw, jnp.float32)])
-    grid = (rows // block_rows,)
-    spec = pl.BlockSpec((block_rows, LANE), lambda i: (i, 0))
+    grid, _, spec = _grid_spec(0, rows, block_rows)
     outs = pl.pallas_call(
         _population_step_kernel, grid=grid,
         in_specs=[_scalar_spec(8)] + [spec] * 12,

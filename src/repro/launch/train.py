@@ -21,6 +21,7 @@ from repro.checkpoint import save
 from repro.core.admm import AdmmConfig
 from repro.core.channel import ChannelConfig
 from repro.data.synthetic import token_dataset
+from repro.launch.mesh import enable_compile_cache, make_mesh
 from repro.models.registry import get_model, list_archs
 from repro.phy import list_scenarios
 from repro.train.llm_trainer import FLConfig, make_fl_train
@@ -164,6 +165,7 @@ def main() -> None:
                          "wall-clock spans (compile vs execute split, "
                          "s/round series) in RUN_DIR/profile.json")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.ota_block_rows is not None:
         # knobs are read lazily at trace time (repro.optflags), so setting
@@ -191,8 +193,8 @@ def main() -> None:
         if n_dev % args.fsdp:
             raise SystemExit(f"--fsdp {args.fsdp} must divide the local "
                              f"device count ({n_dev})")
-        mesh = jax.make_mesh((n_dev // args.fsdp, args.fsdp, 1),
-                             ("data", "fsdp", "model"))
+        mesh = make_mesh((n_dev // args.fsdp, args.fsdp, 1),
+                         ("data", "fsdp", "model"))
 
     faults = guard = None
     crash_at = ()
@@ -336,29 +338,23 @@ def main() -> None:
 
     def aot_compile(jitted, sample_args, rounds_per_dispatch):
         """AOT lower + compile (timed, so the compile/execute split is
-        real) and write ``compile_report.json`` from the optimized HLO.
-        Falls back to the plain jitted callable on any failure — the run
-        itself must never die on a profiling hook."""
+        real) and write ``compile_report.json`` from the optimized HLO."""
         if timer is None:
             return jitted
         from repro.obs.profiling import compile_report
-        try:
-            t_l = time.time()
-            lowered = jitted.lower(*sample_args)
-            t_c = time.time()
-            compiled = lowered.compile()
-            dt_c = time.time() - t_c
-            timer.add("compile", dt_c)
-            if args.run_dir:
-                compile_report(
-                    compiled.as_text(),
-                    os.path.join(args.run_dir, "compile_report.json"),
-                    compile_seconds=dt_c, lower_seconds=t_c - t_l,
-                    rounds_per_dispatch=rounds_per_dispatch)
-            return compiled
-        except Exception as e:
-            print(f"obs: compile report unavailable ({e})", flush=True)
-            return jitted
+        t_l = time.time()
+        lowered = jitted.lower(*sample_args)
+        t_c = time.time()
+        compiled = lowered.compile()
+        dt_c = time.time() - t_c
+        timer.add("compile", dt_c)
+        if args.run_dir:
+            compile_report(
+                compiled.as_text(),
+                os.path.join(args.run_dir, "compile_report.json"),
+                compile_seconds=dt_c, lower_seconds=t_c - t_l,
+                rounds_per_dispatch=rounds_per_dispatch)
+        return compiled
 
     import contextlib
     trace_ctx = contextlib.nullcontext()

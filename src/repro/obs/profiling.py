@@ -3,9 +3,8 @@
 Three independent pieces, all safe no-ops when profiling is off:
 
 * :func:`annotate` / :func:`trace_session` — ``jax.profiler`` named trace
-  annotations and a start/stop trace context around a run.  Everything is
-  try/except-wrapped: a missing or broken profiler backend degrades to a
-  plain timer instead of killing the run.
+  annotations and a start/stop trace context around a run.  A trace that
+  cannot start or stop raises: ``--profile`` never exits 0 without one.
 * :class:`SpanTimer` — wall-clock spans (compile vs execute split, per-block
   seconds) accumulated into a JSON-serialisable dict.
 * :func:`compile_report` — static analysis of a compiled module's optimized
@@ -40,29 +39,18 @@ def annotate(name: str):
 def trace_session(trace_dir: Optional[str]):
     """Start/stop a ``jax.profiler`` trace writing to ``trace_dir``.
 
-    ``None`` disables tracing entirely; profiler failures (unsupported
-    backend, double-start) are swallowed so ``--profile`` can never turn a
-    working run into a crash.
+    ``None`` disables tracing entirely.  Profiler failures propagate.
     """
     if not trace_dir:
         yield
         return
     import jax.profiler
-    started = False
-    try:
-        os.makedirs(trace_dir, exist_ok=True)
-        jax.profiler.start_trace(trace_dir)
-        started = True
-    except Exception:
-        pass
+    os.makedirs(trace_dir, exist_ok=True)
+    jax.profiler.start_trace(trace_dir)
     try:
         yield
     finally:
-        if started:
-            try:
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
+        jax.profiler.stop_trace()
 
 
 class SpanTimer:

@@ -18,6 +18,7 @@ lower baseline and optimized variants of the same code path:
 from __future__ import annotations
 
 import os
+from typing import Optional
 
 #: default chunk sizes (tuned in §Perf iterations)
 ATTN_CHUNK = int(os.environ.get("REPRO_ATTN_CHUNK", "512"))
@@ -35,17 +36,22 @@ def enabled(name: str) -> bool:
 # drop straight into a launch script.
 # ---------------------------------------------------------------------------
 
-def ota_block_rows() -> int:
+def ota_block_rows() -> Optional[int]:
     """Row-block of the flat elementwise OTA kernels (modulate/demodulate/
-    fading step): ``REPRO_OTA_BLOCK_ROWS`` rows × 1024 lanes per tile."""
-    return int(os.environ.get("REPRO_OTA_BLOCK_ROWS", "256"))
+    fading step): ``REPRO_OTA_BLOCK_ROWS`` rows × 1024 lanes per tile, or
+    None when unset (the kernels then size the tile to their VMEM
+    budget)."""
+    env = os.environ.get("REPRO_OTA_BLOCK_ROWS")
+    return int(env) if env else None
 
 
-def ota_block_cols() -> int:
+def ota_block_cols() -> Optional[int]:
     """Column-block of the worker-grid receive/round kernels
     (``kernels/ota_round.py``, ``ota_receive``): ``REPRO_OTA_BLOCK_COLS``
-    lanes per grid step over the packed axis."""
-    return int(os.environ.get("REPRO_OTA_BLOCK_COLS", "1024"))
+    lanes per grid step over the packed axis, or None when unset (the
+    kernels then size the tile from W and their VMEM budget)."""
+    env = os.environ.get("REPRO_OTA_BLOCK_COLS")
+    return int(env) if env else None
 
 
 def ota_worker_chunk() -> int:

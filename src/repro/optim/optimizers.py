@@ -16,8 +16,8 @@ PyTree = Any
 
 
 class OptState(NamedTuple):
-    mu: PyTree     # first moment (zeros for sgd w/o momentum)
-    nu: PyTree     # second moment (unused by sgd)
+    mu: PyTree     # first moment (None for sgd)
+    nu: PyTree     # second moment (None for sgd)
     count: Array
 
 
@@ -27,15 +27,17 @@ class Optimizer:
     update: Callable[[PyTree, OptState, PyTree], Tuple[PyTree, OptState]]
 
 
-def sgd(learning_rate: float, momentum: float = 0.0) -> Optimizer:
+def sgd(learning_rate: float) -> Optimizer:
+    """Plain SGD: keeps no moments (the replicated trainer holds one
+    optimizer state per worker, so a moment tree would cost a full model
+    copy per worker)."""
     def init(params: PyTree) -> OptState:
-        z = jax.tree.map(jnp.zeros_like, params)
-        return OptState(mu=z, nu=z, count=jnp.zeros((), jnp.int32))
+        return OptState(mu=None, nu=None, count=jnp.zeros((), jnp.int32))
 
     def update(grads, state, params):
-        mu = jax.tree.map(lambda m, g: momentum * m + g, state.mu, grads)
-        new_params = jax.tree.map(lambda p, m: p - learning_rate * m, params, mu)
-        return new_params, OptState(mu=mu, nu=state.nu, count=state.count + 1)
+        new_params = jax.tree.map(lambda p, g: p - learning_rate * g,
+                                  params, grads)
+        return new_params, state._replace(count=state.count + 1)
 
     return Optimizer(init=init, update=update)
 

@@ -593,7 +593,6 @@ def make_sketched(model: Model, flcfg: FLConfig, acfg: AdmmConfig,
 
         if not grid:
             return enc(delta, 0)
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         def body(tree):
@@ -604,8 +603,9 @@ def make_sketched(model: Model, flcfg: FLConfig, acfg: AdmmConfig,
             # globally packed delta, pinned in tests/test_sketch_codec.py)
             return jax.lax.psum(s, grid_axes)
 
-        return shard_map(body, mesh=mesh, in_specs=(_param_specs(sspec),),
-                         out_specs=P(), check_rep=False)(delta)
+        return jax.shard_map(body, mesh=mesh,
+                             in_specs=(_param_specs(sspec),),
+                             out_specs=P(), check_vma=False)(delta)
 
     def decode_delta(sspec, s: Array) -> PyTree:
         """(d_s,) global sketch -> delta tree in the params' own sharding.
@@ -636,15 +636,15 @@ def make_sketched(model: Model, flcfg: FLConfig, acfg: AdmmConfig,
 
         if not grid:
             return dec(s, jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         def body(s):
             jm, jf = _grid_idx()
             return dec(s, jm, jf)
 
-        return shard_map(body, mesh=mesh, in_specs=(P(),),
-                         out_specs=_param_specs(sspec), check_rep=False)(s)
+        return jax.shard_map(body, mesh=mesh, in_specs=(P(),),
+                             out_specs=_param_specs(sspec),
+                             check_vma=False)(s)
 
     def init_fn(key: Array) -> SketchFLState:
         kp, kc = jax.random.split(key)

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 from repro.core import cplx, transport
 from repro.core.channel import ChannelConfig, matched_filter_noise, rayleigh
@@ -228,20 +229,20 @@ def _max_compute_out_size(fn, *args):
         nonlocal worst
         for eqn in j.eqns:
             for v in eqn.params.values():
-                if isinstance(v, jax.core.ClosedJaxpr):
+                if isinstance(v, ClosedJaxpr):
                     walk(v.jaxpr)
-                elif isinstance(v, jax.core.Jaxpr):
+                elif isinstance(v, Jaxpr):
                     walk(v)
                 elif isinstance(v, (list, tuple)):
                     for vv in v:
-                        if isinstance(vv, jax.core.ClosedJaxpr):
+                        if isinstance(vv, ClosedJaxpr):
                             walk(vv.jaxpr)
-                        elif isinstance(vv, jax.core.Jaxpr):
+                        elif isinstance(vv, Jaxpr):
                             walk(vv)
             # container eqns (pjit-wrapped jnp.pad etc.) re-report their
             # inner output; the recursion above already scored the body
             if eqn.primitive.name in _LAYOUT_PRIMS or any(
-                    isinstance(v, (jax.core.ClosedJaxpr, jax.core.Jaxpr))
+                    isinstance(v, (ClosedJaxpr, Jaxpr))
                     for v in eqn.params.values()):
                 continue
             for ov in eqn.outvars:

@@ -12,6 +12,7 @@ from repro.core import cplx, make, transport
 from repro.core.admm import AdmmConfig
 from repro.core.channel import ChannelConfig, rayleigh
 from repro.core.tree_ota import init_channel_packed, step_channel_packed
+from repro.launch.mesh import make_mesh
 from repro.phy import (GeometryConfig, bessel_j0, doppler_rho,
                        gauss_markov_step, list_scenarios, make_scenario,
                        participation_mask)
@@ -522,7 +523,7 @@ def test_llm_trainer_scenario_model_parallel_uses_shard_local_layout():
                      scenario="markov-doppler")
 
     # model=1 mesh: the canonical single-buffer packed layout
-    mesh1 = jax.make_mesh((1, 1), ("data", "model"))
+    mesh1 = make_mesh((1, 1), ("data", "model"))
     init1, _ = make_fl_train(m, flcfg, AdmmConfig(),
                              ChannelConfig(n_workers=2), mesh=mesh1)
     st1 = jax.eval_shape(init1, KEY)
@@ -530,7 +531,7 @@ def test_llm_trainer_scenario_model_parallel_uses_shard_local_layout():
 
     # model=2 mesh (abstract — the layout decision needs no devices): the
     # shard-local (W, d_pad) layout, PhyState fading planes included
-    mesh2 = jax.sharding.AbstractMesh((("data", 1), ("model", 2)))
+    mesh2 = jax.sharding.AbstractMesh((1, 2), ("data", "model"))
     init2, _ = make_fl_train(m, flcfg, AdmmConfig(),
                              ChannelConfig(n_workers=2), mesh=mesh2)
     st2 = jax.eval_shape(init2, KEY)
@@ -559,7 +560,7 @@ def test_trainer_built_without_mesh_refuses_model_parallel_trace():
                                   ChannelConfig(n_workers=2))   # no mesh
     st = jax.eval_shape(init_fn, KEY)
     batch = jax.ShapeDtypeStruct((2, 1, 8), jnp.int32)
-    mesh = jax.sharding.AbstractMesh((("data", 1), ("model", 2)))
+    mesh = jax.sharding.AbstractMesh((1, 2), ("data", "model"))
     with axis_rules(mesh):
         with pytest.raises(ValueError, match="pass mesh="):
             jax.eval_shape(step, st, {"tokens": batch}, KEY)
@@ -572,7 +573,7 @@ def test_trainer_built_without_mesh_refuses_model_parallel_trace():
 def test_build_train_spec_with_scenario():
     from repro.launch.specs import build_train_spec
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     spec = build_train_spec("granite-8b", mesh, multi_pod=False,
                             reduced=True, scenario="markov-doppler")
     assert spec.meta["scenario"] == "markov-doppler"
@@ -592,7 +593,7 @@ def test_build_train_spec_sketched_accepts_scenario():
     on the (W, d_s) sketch planes instead of the full packed dim."""
     from repro.launch.specs import build_train_spec
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     spec = build_train_spec("granite-8b", mesh, multi_pod=False,
                             reduced=True, scenario="markov-doppler",
                             fl_mode="sketched", sketch_ratio=64)

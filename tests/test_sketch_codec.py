@@ -150,7 +150,6 @@ _MESH_SCRIPT = r"""
 import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.core.packing import (build_shard_packspec, pack, pack_shard_local,
                                 shard_perm_local, shard_valid_mask,
                                 unpack_shard_local)
@@ -182,8 +181,8 @@ def enc_body(t):
 
 
 in_specs = ({k: specs[k] for k in theta},)
-enc = jax.jit(shard_map(enc_body, mesh=mesh, in_specs=in_specs,
-                        out_specs=P(), check_rep=False))
+enc = jax.jit(jax.shard_map(enc_body, mesh=mesh, in_specs=in_specs,
+                            out_specs=P(), check_vma=False))
 s = enc(put)
 want = encode_packed(pack(ss.spec, theta), d_s, 17)
 np.testing.assert_allclose(np.asarray(s), np.asarray(want),
@@ -204,9 +203,9 @@ def dec_body(sk):
     return unpack_shard_local(ss, buf, rseg, cast=False)
 
 
-dec = jax.jit(shard_map(dec_body, mesh=mesh, in_specs=(P(),),
-                        out_specs={k: specs[k] for k in theta},
-                        check_rep=False))
+dec = jax.jit(jax.shard_map(dec_body, mesh=mesh, in_specs=(P(),),
+                            out_specs={k: specs[k] for k in theta},
+                            check_vma=False))
 out = dec(s)
 # decoded tree keeps the model-parallel parameter sharding (no all-gather)
 for k in theta:
