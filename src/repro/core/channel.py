@@ -24,6 +24,7 @@ import jax.numpy as jnp
 
 from repro.core import cplx
 from repro.core.cplx import Complex
+from repro.obs.profiling import layer
 
 Array = jax.Array
 
@@ -147,6 +148,7 @@ def step_channel(key: Array, blk: ChannelBlock, cfg: ChannelConfig) -> ChannelBl
     )
 
 
+@layer("ota_noise")
 def matched_filter_noise(key: Array, shape: Tuple[int, ...], cfg: ChannelConfig) -> Complex:
     """Receiver noise after the correlator (Eq. 23): CN(0, N0/T), or zero."""
     if not cfg.noisy:

@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.cplx import Complex
+from repro.obs.profiling import layer
 
 Array = jax.Array
 PyTree = Any
@@ -117,6 +118,7 @@ def _dus_pack(flat: List[Array], offsets, d: int) -> Array:
     return buf
 
 
+@layer("ota_pack")
 def pack(spec: PackSpec, tree: PyTree) -> Array:
     """``tree`` -> ``lead + (spec.d,)`` f32 buffer (row-major per leaf)."""
     leaves = jax.tree_util.tree_flatten(tree, is_leaf=_is_cplx)[0]
@@ -128,6 +130,7 @@ def pack(spec: PackSpec, tree: PyTree) -> Array:
     return flat[0] if len(flat) == 1 else _dus_pack(flat, spec.offsets, spec.d)
 
 
+@layer("ota_pack")
 def unpack(spec: PackSpec, buf: Array, cast: bool = True) -> PyTree:
     """``lead + (spec.d,)`` buffer -> pytree; ``cast=True`` restores the
     recorded leaf dtypes, ``cast=False`` keeps the buffer dtype (the analog
@@ -144,6 +147,7 @@ def unpack(spec: PackSpec, buf: Array, cast: bool = True) -> PyTree:
     return jax.tree_util.tree_unflatten(spec.treedef, out)
 
 
+@layer("ota_pack")
 def pack_cplx(spec: PackSpec, tree: PyTree) -> Complex:
     """Complex-leaf tree -> Complex of packed planes."""
     flats = jax.tree_util.tree_flatten(tree, is_leaf=_is_cplx)[0]
@@ -152,6 +156,7 @@ def pack_cplx(spec: PackSpec, tree: PyTree) -> Complex:
     return Complex(pack(spec, re), pack(spec, im))
 
 
+@layer("ota_pack")
 def unpack_cplx(spec: PackSpec, buf: Complex) -> PyTree:
     """Complex packed planes -> tree of Complex leaves (f32: duals/fading
     always live in f32, never the parameter dtype)."""
@@ -415,6 +420,7 @@ def _split_idx(sspec: ShardPackSpec, shard_idx):
     return shard_idx % sspec.n_model, shard_idx // sspec.n_model
 
 
+@layer("ota_pack")
 def pack_shard_local(sspec: ShardPackSpec, tree: PyTree, shard_idx) -> Array:
     """Pack ONE shard's resident data: every leaf arrives as the slice its
     PartitionSpec makes resident (class A sliced on both dims, B on the
@@ -458,6 +464,7 @@ def _seg_unpack(sspec: ShardPackSpec, seg, idxs, offs, out, cast: bool):
         out[i] = piece.reshape(lead + sspec.spec.shapes[i])
 
 
+@layer("ota_pack")
 def unpack_shard_local(sspec: ShardPackSpec, buf: Array,
                        rep_seg: Optional[Array] = None,
                        cast: bool = False, *,

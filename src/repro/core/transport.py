@@ -44,6 +44,7 @@ from repro.core import cplx
 from repro.core.channel import ChannelConfig, matched_filter_noise
 from repro.core.cplx import Complex
 from repro.core.power import alpha_from_energy
+from repro.obs.profiling import layer
 
 Array = jax.Array
 ReduceFn = Callable[[Array], Array]
@@ -128,6 +129,7 @@ def _mask_planes(x: Complex, mask: Array) -> Complex:
     return cplx.cwhere(mb, x, cplx.czero(x.re.shape, x.re.dtype))
 
 
+@layer("ota_receive")
 def receive(signals: Complex, h: Complex, key: Array, ccfg: ChannelConfig,
             inv_alpha: Array | float = 1.0, *,
             reduce_fn: Optional[ReduceFn] = None,
@@ -215,6 +217,7 @@ def ota_accumulate(acc: OtaAccumulator, signal: Complex, h: Complex,
         sumh2=acc.sumh2 + cplx.abs2(h))
 
 
+@layer("ota_receive")
 def ota_receive_accumulated(acc: OtaAccumulator, key: Array,
                             ccfg: ChannelConfig,
                             inv_alpha: Array | float = 1.0, *,
@@ -229,6 +232,7 @@ def ota_receive_accumulated(acc: OtaAccumulator, key: Array,
                       backend=backend)
 
 
+@layer("ota_dual")
 def dual_update(lam: Complex, h: Complex, theta: Array, Theta: Array,
                 rho: float, noise_re: Array | float = 0.0,
                 *, backend: Optional[str] = None) -> Complex:
@@ -338,6 +342,7 @@ def power_scale(signals: Complex, ccfg: ChannelConfig,
 # The full uplink (Alg. 1, the "transport" entry point)
 # ---------------------------------------------------------------------------
 
+@layer("ota_receive")
 def ota_uplink(theta: Array, lam: Complex, h: Complex, key: Array,
                rho: float, ccfg: ChannelConfig, *,
                power_control: bool = True,
@@ -426,6 +431,7 @@ def round_telemetry(tel, y_re: Array, noise_re: Array, inv_alpha: Array,
     return out
 
 
+@layer("ota_noise")
 def matched_filter_noise_re(key: Array, shape, ccfg: ChannelConfig) -> Array:
     """REAL plane of :func:`~repro.core.channel.matched_filter_noise`,
     without generating the imaginary draw the receiver never reads.
@@ -591,6 +597,7 @@ def _ota_round_streamed(theta: Array, lam: Complex, h: Complex, key: Array,
     return Theta.reshape(out_shape), inv_alpha, h_air
 
 
+@layer("ota_receive")
 def ota_round_fused(theta: Array, lam: Complex, h: Complex, key: Array,
                     rho: float, ccfg: ChannelConfig, *,
                     power_control: bool = True,

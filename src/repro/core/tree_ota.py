@@ -32,6 +32,7 @@ from repro.core.packing import (ShardPackSpec, build_packspec, pack,
                                 shard_rep_chunk, shard_valid_mask, unpack,
                                 unpack_cplx, unpack_shard_local)
 from repro.obs import merge_disjoint, resolve as resolve_telemetry
+from repro.obs.profiling import layer
 
 Array = jax.Array
 PyTree = Any
@@ -83,6 +84,7 @@ def init_channel_tree(key: Array, theta_w: PyTree) -> TreeChannel:
     return TreeChannel(h=h, age=jnp.zeros((), jnp.int32))
 
 
+@layer("chan_step")
 def step_channel_tree(key: Array, chan: TreeChannel,
                       ccfg: ChannelConfig) -> Tuple[TreeChannel, Array]:
     """Redraw every leaf's fading block at coherence boundaries."""
@@ -99,6 +101,7 @@ def step_channel_tree(key: Array, chan: TreeChannel,
     return TreeChannel(h=h, age=new_age), redraw
 
 
+@layer("penalty")
 def tree_penalty_grad(theta: PyTree, lam: PyTree, h: PyTree, Theta: PyTree,
                       rho: float) -> PyTree:
     """Leafwise Re{λ*h} + ρ|h|²(θ − Θ), broadcasting Θ over the worker dim."""
@@ -151,6 +154,7 @@ def init_channel_packed(key: Array, n_workers: int, d: int) -> TreeChannel:
                        age=jnp.zeros((), jnp.int32))
 
 
+@layer("chan_step")
 def step_channel_packed(key: Array, chan: TreeChannel,
                         ccfg: ChannelConfig) -> Tuple[TreeChannel, Array]:
     """Coherence-boundary redraw of a packed fading buffer (one draw)."""
@@ -442,23 +446,26 @@ def ota_tree_round_leafwise(theta: PyTree, lam: PyTree, h: PyTree, key: Array,
     """
     rho = acfg.rho
     h_wkr = h if h_tx is None else h_tx
-    signals = _modulate_tree(theta, lam, h_wkr, rho, backend)
+    with layer("ota_receive"):
+        signals = _modulate_tree(theta, lam, h_wkr, rho, backend)
 
-    if acfg.power_control:
-        budget = ccfg.transmit_power * _tree_size(signals)
-        inv_alpha = transport.inv_alpha_from_energy(
-            _tree_energy_per_worker(signals), budget,
-            min_reduce_fn=min_reduce_fn, mask=mask)
-    else:
-        inv_alpha = jnp.asarray(1.0, jnp.float32)
+        if acfg.power_control:
+            budget = ccfg.transmit_power * _tree_size(signals)
+            inv_alpha = transport.inv_alpha_from_energy(
+                _tree_energy_per_worker(signals), budget,
+                min_reduce_fn=min_reduce_fn, mask=mask)
+        else:
+            inv_alpha = jnp.asarray(1.0, jnp.float32)
 
-    s_leaves, treedef = jax.tree_util.tree_flatten(signals, is_leaf=_is_cplx)
-    h_leaves = jax.tree_util.tree_flatten(h, is_leaf=_is_cplx)[0]
-    keys = _leaf_keys(key, signals)
-    Theta_new = jax.tree_util.tree_unflatten(treedef, [
-        transport.receive(s, hh, k, ccfg, inv_alpha,
-                          reduce_fn=reduce_fn, mask=mask, backend=backend)
-        for s, hh, k in zip(s_leaves, h_leaves, keys)])
+        s_leaves, treedef = jax.tree_util.tree_flatten(signals,
+                                                       is_leaf=_is_cplx)
+        h_leaves = jax.tree_util.tree_flatten(h, is_leaf=_is_cplx)[0]
+        keys = _leaf_keys(key, signals)
+        Theta_new = jax.tree_util.tree_unflatten(treedef, [
+            transport.receive(s, hh, k, ccfg, inv_alpha,
+                              reduce_fn=reduce_fn, mask=mask,
+                              backend=backend)
+            for s, hh, k in zip(s_leaves, h_leaves, keys)])
 
     lam_new = _zmap(
         lambda l, hh, t, T: transport.dual_update(l, hh, t, T, rho,
@@ -564,6 +571,7 @@ def _segs_psum(sspec: ShardPackSpec, plane: Array, jm, jf, model_axis: str,
     return b_seg, c_seg, rep_seg
 
 
+@layer("ota_pack")
 def unpack_cplx_shard_local(sspec: ShardPackSpec, buf: Complex, mesh,
                             model_axis: str = "model",
                             fsdp_axis: str = "fsdp") -> PyTree:
@@ -744,124 +752,125 @@ def ota_tree_round_shard_local(theta: PyTree, lam_p: Complex, h_p: Complex,
             base = jnp.ones(bad.shape, bool) if mask is None else mask
             evicted_l = bad & base
             mask = base & ~evicted_l
-        healthy_l = retries_l = None
-        if use_fused:
-            # one pass over this shard's worker planes (modulate + energy +
-            # mask + superposition + pilot fused); only the O(d_local)
-            # epilogue and the scalar/energy consensus collectives remain
-            y_l, p2_l, energy_l, _ = transport.ota_round_stats(
-                theta_tx, lam, h, rho, mask=mask, h_tx=h_tx,
-                backend=backend, block_cols=block_cols)
-            mrf = None if local_w else (lambda a: jax.lax.pmin(a, daxes))
-            energy = (jax.lax.psum(energy_l, sax_entry)
-                      if acfg.power_control else None)
-            if not local_w:
-                y_l = jax.lax.psum(y_l, daxes)
-                p2_l = jax.lax.psum(p2_l, daxes)
-            noise_key = jax.random.fold_in(key, j)
-            if has_guard:
-                from repro.core import power as _power
+        with layer("ota_receive"):
+            healthy_l = retries_l = None
+            if use_fused:
+                # one pass over this shard's worker planes (modulate + energy +
+                # mask + superposition + pilot fused); only the O(d_local)
+                # epilogue and the scalar/energy consensus collectives remain
+                y_l, p2_l, energy_l, _ = transport.ota_round_stats(
+                    theta_tx, lam, h, rho, mask=mask, h_tx=h_tx,
+                    backend=backend, block_cols=block_cols)
+                mrf = None if local_w else (lambda a: jax.lax.pmin(a, daxes))
+                energy = (jax.lax.psum(energy_l, sax_entry)
+                          if acfg.power_control else None)
+                if not local_w:
+                    y_l = jax.lax.psum(y_l, daxes)
+                    p2_l = jax.lax.psum(p2_l, daxes)
+                noise_key = jax.random.fold_in(key, j)
+                if has_guard:
+                    from repro.core import power as _power
 
-                def gsum(s):
-                    return jax.lax.psum(s, sax_entry)
+                    def gsum(s):
+                        return jax.lax.psum(s, sax_entry)
 
-                def epi(k, attempt, with_burst):
+                    def epi(k, attempt, with_burst):
+                        if acfg.power_control:
+                            b = _power.retry_power_budget(budget, attempt,
+                                                          guard.power_backoff)
+                            ia = transport.inv_alpha_from_energy(
+                                energy, b, min_reduce_fn=mrf, mask=mask)
+                        else:
+                            ia = jnp.asarray(1.0, jnp.float32)
+                        n = transport.matched_filter_noise_re(k, y_l.shape,
+                                                              ccfg)
+                        if with_burst:
+                            kb = jax.random.fold_in(k, _fg.BURST_SALT)
+                            n = n + burst * jax.random.normal(kb, n.shape,
+                                                              jnp.float32)
+                        n_eff = n * ia
+                        Th = transport.demodulate(y_l, p2_l, n_eff, 1.0,
+                                                  backend=backend)
+                        bad = gsum(jnp.sum((~jnp.isfinite(Th))
+                                           .astype(jnp.float32)))
+                        ok = bad == 0.0
+                        sig = npw = dummy
+                        if guard.snr_floor_db is not None or has_tel:
+                            sig = gsum(jnp.sum(y_l * y_l))
+                            npw = gsum(jnp.sum(n_eff * n_eff))
+                        if guard.snr_floor_db is not None:
+                            thr = 10.0 ** (guard.snr_floor_db / 10.0)
+                            ok &= sig >= thr * npw
+                        return Th, ia, ok, sig, npw
+
+                    Theta_p, inv_alpha, ok, sig_g, npw_g = epi(
+                        noise_key, jnp.int32(0), has_burst)
+                    retries_l = jnp.zeros((), jnp.int32)
+                    # statically unrolled retries: SPMD-safe (no collective in
+                    # control flow), same keys/backoff a lazy loop would use
+                    for a in range(1, guard.retries + 1):
+                        ka = jax.random.fold_in(noise_key, _fg.RETRY_SALT + a)
+                        Th_a, ia_a, ok_a, sig_a, npw_a = epi(ka, jnp.int32(a),
+                                                             False)
+                        take = ~ok
+                        Theta_p = jnp.where(take, Th_a, Theta_p)
+                        inv_alpha = jnp.where(take, ia_a, inv_alpha)
+                        sig_g = jnp.where(take, sig_a, sig_g)
+                        npw_g = jnp.where(take, npw_a, npw_g)
+                        retries_l = retries_l + take.astype(jnp.int32)
+                        ok = jnp.where(take, ok_a, ok)
+                    healthy_l = ok
+                else:
                     if acfg.power_control:
-                        b = _power.retry_power_budget(budget, attempt,
-                                                      guard.power_backoff)
-                        ia = transport.inv_alpha_from_energy(
-                            energy, b, min_reduce_fn=mrf, mask=mask)
+                        inv_alpha = transport.inv_alpha_from_energy(
+                            energy, budget, min_reduce_fn=mrf, mask=mask)
                     else:
-                        ia = jnp.asarray(1.0, jnp.float32)
-                    n = transport.matched_filter_noise_re(k, y_l.shape,
-                                                          ccfg)
-                    if with_burst:
-                        kb = jax.random.fold_in(k, _fg.BURST_SALT)
-                        n = n + burst * jax.random.normal(kb, n.shape,
-                                                          jnp.float32)
-                    n_eff = n * ia
-                    Th = transport.demodulate(y_l, p2_l, n_eff, 1.0,
-                                              backend=backend)
-                    bad = gsum(jnp.sum((~jnp.isfinite(Th))
-                                       .astype(jnp.float32)))
-                    ok = bad == 0.0
-                    sig = npw = dummy
-                    if guard.snr_floor_db is not None or has_tel:
-                        sig = gsum(jnp.sum(y_l * y_l))
-                        npw = gsum(jnp.sum(n_eff * n_eff))
-                    if guard.snr_floor_db is not None:
-                        thr = 10.0 ** (guard.snr_floor_db / 10.0)
-                        ok &= sig >= thr * npw
-                    return Th, ia, ok, sig, npw
-
-                Theta_p, inv_alpha, ok, sig_g, npw_g = epi(
-                    noise_key, jnp.int32(0), has_burst)
-                retries_l = jnp.zeros((), jnp.int32)
-                # statically unrolled retries: SPMD-safe (no collective in
-                # control flow), same keys/backoff a lazy loop would use
-                for a in range(1, guard.retries + 1):
-                    ka = jax.random.fold_in(noise_key, _fg.RETRY_SALT + a)
-                    Th_a, ia_a, ok_a, sig_a, npw_a = epi(ka, jnp.int32(a),
-                                                         False)
-                    take = ~ok
-                    Theta_p = jnp.where(take, Th_a, Theta_p)
-                    inv_alpha = jnp.where(take, ia_a, inv_alpha)
-                    sig_g = jnp.where(take, sig_a, sig_g)
-                    npw_g = jnp.where(take, npw_a, npw_g)
-                    retries_l = retries_l + take.astype(jnp.int32)
-                    ok = jnp.where(take, ok_a, ok)
-                healthy_l = ok
+                        inv_alpha = jnp.asarray(1.0, jnp.float32)
+                    noise_re = transport.matched_filter_noise_re(
+                        noise_key, y_l.shape, ccfg)
+                    if has_burst:
+                        kb = jax.random.fold_in(noise_key, _fg.BURST_SALT)
+                        noise_re = noise_re + burst * jax.random.normal(
+                            kb, noise_re.shape, jnp.float32)
+                    Theta_p = transport.demodulate(y_l, p2_l, noise_re,
+                                                   inv_alpha, backend=backend)
+                    sig_g = npw_g = dummy
+                    if has_tel:
+                        # y_l is replicated over the data axes here, so the
+                        # global power sums reduce over the shard grid only —
+                        # the guard's exact gsum
+                        n_eff = noise_re * inv_alpha
+                        sig_g = jax.lax.psum(jnp.sum(y_l * y_l), sax_entry)
+                        npw_g = jax.lax.psum(jnp.sum(n_eff * n_eff), sax_entry)
+                e_tx = dummy
+                if want_energy_out:
+                    alpha = jnp.where(inv_alpha > 0,
+                                      1.0 / jnp.maximum(inv_alpha, 1e-38), 0.0)
+                    e_tx = energy * (alpha * alpha)
+                    if mask is not None:
+                        e_tx = jnp.where(mask, e_tx, 0.0)
+                h_wkr = h if h_tx is None else h_tx
             else:
+                h_wkr = h if h_tx is None else h_tx
+                signals = transport.modulate(theta_p, lam, h_wkr, rho,
+                                             backend=backend)
                 if acfg.power_control:
+                    # per-worker TOTAL energy: every element owned by one shard
+                    energy = jax.lax.psum(transport.worker_energy(signals),
+                                          sax_entry)
                     inv_alpha = transport.inv_alpha_from_energy(
-                        energy, budget, min_reduce_fn=mrf, mask=mask)
+                        energy, budget,
+                        min_reduce_fn=None if local_w
+                        else (lambda a: jax.lax.pmin(a, daxes)),
+                        mask=mask)
                 else:
                     inv_alpha = jnp.asarray(1.0, jnp.float32)
-                noise_re = transport.matched_filter_noise_re(
-                    noise_key, y_l.shape, ccfg)
-                if has_burst:
-                    kb = jax.random.fold_in(noise_key, _fg.BURST_SALT)
-                    noise_re = noise_re + burst * jax.random.normal(
-                        kb, noise_re.shape, jnp.float32)
-                Theta_p = transport.demodulate(y_l, p2_l, noise_re,
-                                               inv_alpha, backend=backend)
-                sig_g = npw_g = dummy
-                if has_tel:
-                    # y_l is replicated over the data axes here, so the
-                    # global power sums reduce over the shard grid only —
-                    # the guard's exact gsum
-                    n_eff = noise_re * inv_alpha
-                    sig_g = jax.lax.psum(jnp.sum(y_l * y_l), sax_entry)
-                    npw_g = jax.lax.psum(jnp.sum(n_eff * n_eff), sax_entry)
-            e_tx = dummy
-            if want_energy_out:
-                alpha = jnp.where(inv_alpha > 0,
-                                  1.0 / jnp.maximum(inv_alpha, 1e-38), 0.0)
-                e_tx = energy * (alpha * alpha)
-                if mask is not None:
-                    e_tx = jnp.where(mask, e_tx, 0.0)
-            h_wkr = h if h_tx is None else h_tx
-        else:
-            h_wkr = h if h_tx is None else h_tx
-            signals = transport.modulate(theta_p, lam, h_wkr, rho,
-                                         backend=backend)
-            if acfg.power_control:
-                # per-worker TOTAL energy: every element owned by one shard
-                energy = jax.lax.psum(transport.worker_energy(signals),
-                                      sax_entry)
-                inv_alpha = transport.inv_alpha_from_energy(
-                    energy, budget,
-                    min_reduce_fn=None if local_w
-                    else (lambda a: jax.lax.pmin(a, daxes)),
-                    mask=mask)
-            else:
-                inv_alpha = jnp.asarray(1.0, jnp.float32)
-            noise_key = jax.random.fold_in(key, j)
-            Theta_p = transport.receive(
-                signals, h, noise_key, ccfg, inv_alpha,
-                reduce_fn=None if local_w
-                else (lambda x: jax.lax.psum(jnp.sum(x, axis=0), daxes)),
-                mask=mask, backend=backend)
+                noise_key = jax.random.fold_in(key, j)
+                Theta_p = transport.receive(
+                    signals, h, noise_key, ccfg, inv_alpha,
+                    reduce_fn=None if local_w
+                    else (lambda x: jax.lax.psum(jnp.sum(x, axis=0), daxes)),
+                    mask=mask, backend=backend)
         # duals update from the worker's TRUE planes (theta_p, not the
         # faulted theta_tx); `mask` already excludes evicted offenders
         lam_new = transport.dual_update(lam, h_wkr, theta_p, Theta_p, rho,
@@ -879,10 +888,11 @@ def ota_tree_round_shard_local(theta: PyTree, lam_p: Complex, h_p: Complex,
             valid = shard_valid_mask(sspec, j)
             lam_new = cplx.cwhere(valid[None, :], lam_new,
                                   cplx.czero(lam_new.re.shape))
-        b_seg, c_seg, rep_seg = _segs_psum(sspec, Theta_p, jm, jf,
-                                           model_axis, fsdp_axis)
-        Theta_tree = unpack_shard_local(sspec, Theta_p, rep_seg,
-                                        b_seg=b_seg, c_seg=c_seg)
+        with layer("ota_pack"):
+            b_seg, c_seg, rep_seg = _segs_psum(sspec, Theta_p, jm, jf,
+                                               model_axis, fsdp_axis)
+            Theta_tree = unpack_shard_local(sspec, Theta_p, rep_seg,
+                                            b_seg=b_seg, c_seg=c_seg)
         out = [Theta_tree, lam_new, inv_alpha]
         if has_stale:
             out.append(stale_next)

@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import power, transport
+from repro.obs.profiling import layer
 
 Array = Any
 
@@ -240,6 +241,7 @@ def _rows_nonfinite(*planes) -> Array:
     return bad
 
 
+@layer("ota_receive")
 def guarded_ota_round(theta: Array, lam, h, key: Array, rho: float,
                       ccfg, gcfg: GuardConfig, *,
                       power_control: bool = True,

@@ -10,6 +10,7 @@ production mesh — the launcher is mesh-agnostic.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import os
 import time
@@ -162,8 +163,11 @@ def main() -> None:
                          "trainer)")
     ap.add_argument("--profile", action="store_true",
                     help="jax.profiler trace into RUN_DIR/trace plus "
-                         "wall-clock spans (compile vs execute split, "
-                         "s/round series) in RUN_DIR/profile.json")
+                         "wall-clock spans in RUN_DIR/profile.json: "
+                         "compile, execute, and each round's batch, "
+                         "dispatch, readback, log and checkpoint (also "
+                         "trace annotations inside a 'round' trace step), "
+                         "and compilations after the first dispatch")
     args = ap.parse_args()
     enable_compile_cache()
 
@@ -336,18 +340,25 @@ def main() -> None:
               f"{json.dumps({k: round(v, 4) for k, v in m.items() if k != 'loss'})}",
               flush=True)
 
+    def span(name):
+        """The host phase ``name`` as a ``SpanTimer`` span, which is also a
+        trace annotation; nothing when the run is neither logged nor
+        profiled."""
+        return timer.span(name) if timer is not None \
+            else contextlib.nullcontext()
+
     def aot_compile(jitted, sample_args, rounds_per_dispatch):
         """AOT lower + compile (timed, so the compile/execute split is
         real) and write ``compile_report.json`` from the optimized HLO."""
         if timer is None:
             return jitted
         from repro.obs.profiling import compile_report
-        t_l = time.time()
+        t_l = time.perf_counter()
         lowered = jitted.lower(*sample_args)
-        t_c = time.time()
-        compiled = lowered.compile()
-        dt_c = time.time() - t_c
-        timer.add("compile", dt_c)
+        t_c = time.perf_counter()
+        with timer.span("compile"):
+            compiled = lowered.compile()
+        dt_c = timer.series["compile"][-1]
         if args.run_dir:
             compile_report(
                 compiled.as_text(),
@@ -356,7 +367,6 @@ def main() -> None:
                 rounds_per_dispatch=rounds_per_dispatch)
         return compiled
 
-    import contextlib
     trace_ctx = contextlib.nullcontext()
     if args.profile and args.run_dir:
         from repro.obs.profiling import trace_session
@@ -393,21 +403,32 @@ def main() -> None:
                 block)
             last = r0
             for start in range(r0, args.rounds, block):
-                tb = time.time()
-                st, ms = run_block(data, st, jnp.arange(start, start + block,
-                                                        dtype=jnp.int32))
-                if sink is not None or timer is not None:
-                    ms = jax.device_get(ms)      # host sync: timing is real
-                    bs = time.time() - tb
-                    if timer is not None:
-                        timer.add("execute", bs)
-                    if sink is not None:
-                        # EVERY round of the block goes to the structured
-                        # log; stdout keeps the last-round summary below
-                        sink.log_rounds(start, ms)
-                        sink.log_block(start + block - 1, bs, block)
-                log(start + block - 1, jax.tree.map(lambda x: x[-1], ms))
-                last = maybe_checkpoint(start + block, st, last)
+                # one trace step per block, numbered by its first round
+                with jax.profiler.StepTraceAnnotation("round",
+                                                      step_num=start):
+                    with span("execute"):
+                        with span("batch"):
+                            rs = jnp.arange(start, start + block,
+                                            dtype=jnp.int32)
+                        with span("dispatch"):
+                            st, ms = run_block(data, st, rs)
+                        if timer is not None:     # logged or profiled
+                            with span("readback"):
+                                # host sync: timing is real
+                                ms = jax.device_get(ms)
+                    with span("log"):
+                        if sink is not None:
+                            # EVERY round of the block goes to the
+                            # structured log; stdout keeps the last-round
+                            # summary below
+                            sink.log_rounds(start, ms)
+                            sink.log_block(start + block - 1,
+                                           timer.series["execute"][-1],
+                                           block)
+                        log(start + block - 1,
+                            jax.tree.map(lambda x: x[-1], ms))
+                    with span("checkpoint"):
+                        last = maybe_checkpoint(start + block, st, last)
         else:
             step = jax.jit(train_step, donate_argnums=(0,))
             step = aot_compile(
@@ -416,19 +437,24 @@ def main() -> None:
                  jax.random.fold_in(key, 2000 + r0)), 1)
             last = r0
             for r in range(r0, args.rounds):
-                tr = time.time()
-                batch = make_batch(data, jax.random.fold_in(key, 1000 + r))
-                st, metrics = step(st, batch,
-                                   jax.random.fold_in(key, 2000 + r))
-                if sink is not None or timer is not None:
-                    metrics = jax.device_get(metrics)
-                    if timer is not None:
-                        timer.add("execute", time.time() - tr)
-                    if sink is not None:
-                        sink.log_round(r, metrics)
-                if r % args.log_every == 0 or r == args.rounds - 1:
-                    log(r, metrics)
-                last = maybe_checkpoint(r + 1, st, last)
+                with jax.profiler.StepTraceAnnotation("round", step_num=r):
+                    with span("execute"):
+                        with span("batch"):
+                            batch = make_batch(
+                                data, jax.random.fold_in(key, 1000 + r))
+                            kr = jax.random.fold_in(key, 2000 + r)
+                        with span("dispatch"):
+                            st, metrics = step(st, batch, kr)
+                        if timer is not None:     # logged or profiled
+                            with span("readback"):
+                                metrics = jax.device_get(metrics)
+                    with span("log"):
+                        if sink is not None:
+                            sink.log_round(r, metrics)
+                        if r % args.log_every == 0 or r == args.rounds - 1:
+                            log(r, metrics)
+                    with span("checkpoint"):
+                        last = maybe_checkpoint(r + 1, st, last)
     dt = time.time() - t0
     print(f"done: {args.rounds} rounds in {dt:.1f}s "
           f"({dt / args.rounds:.2f}s/round)")
@@ -437,14 +463,17 @@ def main() -> None:
         sink.close()
     if timer is not None:
         summ = timer.summary()
+        recompiles = timer.compiles_after_first
         if args.run_dir:
             with open(os.path.join(args.run_dir, "profile.json"), "w") as f:
-                json.dump({"spans": summ, "series": timer.series}, f,
+                json.dump({"spans": summ, "series": timer.series,
+                           "compiles_after_first": recompiles}, f,
                           indent=2, sort_keys=True)
                 f.write("\n")
         parts = ", ".join(f"{k}={v['seconds']:.2f}s/{int(v['count'])}x"
                           for k, v in sorted(summ.items()))
-        print(f"profile: {parts}", flush=True)
+        print(f"profile: {parts}, compiles_after_first={recompiles}",
+              flush=True)
 
     if args.checkpoint:
         Theta = st.Theta

@@ -13,8 +13,10 @@ Three tiers, matching the three places a run can be observed:
 2. **Structured run logs** (``repro.obs.sink.MetricsSink``): one JSONL
    event per round plus a run manifest under ``--run-dir``.
 3. **Profiling hooks** (``repro.obs.profiling``): ``jax.profiler`` trace
-   annotations, wall-clock spans with a compile/execute split, and an
-   HLO compile report built on ``launch.hlo_analysis``.
+   annotations, wall-clock spans with a compile/execute split, named
+   layer scopes (``layer``, vocabulary ``LAYERS`` below) that label the
+   compiled step's ops by the layer they belong to, and an HLO compile
+   report built on ``launch.hlo_analysis``.
 
 Canonical metric-key schema
 ---------------------------
@@ -66,6 +68,37 @@ present when a ``GuardConfig`` is active):
 Keys starting with ``_`` (e.g. ``_fault_aux``) are private plumbing that
 callers pop before metrics reach a sink.
 
+Layer scopes (``LAYERS``)
+------------------------
+
+``repro.obs.profiling.layer(name)`` opens ``jax.named_scope(name)``; the
+name lands in every HLO instruction's ``metadata={op_name=...}`` as one
+path segment (wrapped by transforms, e.g. ``transpose(jvp(penalty))``),
+at no run-time cost.  A device trace names ops by instruction, so the
+metadata maps each op's device time to a layer.  Five top-level scopes,
+which never nest in one another:
+
+    ``chan_step``    the fading/phy step (``tree_ota.step_channel_packed``,
+                     ``step_channel_tree``, ``phy.scenario.Scenario.step``)
+    ``local_steps``  the workers' local-step ``lax.scan`` (trainers)
+    ``ota_pack``     packing θ/Θ/λ/h into and out of the (W, D) planes
+                     (``core.packing`` packers)
+    ``ota_receive``  modulate → power-scale → superpose → noise →
+                     demodulate (``transport.ota_round_fused``,
+                     ``ota_uplink``, ``receive``, the shard-local and
+                     guarded receives)
+    ``ota_dual``     the dual update (``transport.dual_update``)
+
+and two nested ones:
+
+    ``penalty``      the prox penalty gradient, inside ``local_steps``
+                     (``tree_ota.tree_penalty_grad``)
+    ``ota_noise``    the matched-filter noise draw, inside ``ota_receive``
+
+A scope opened inside another top-level scope (a packer the receive
+calls, a receive inside a receive) or a nested scope outside its parent
+opens nothing: the enclosing layer owns that work.
+
 JSONL event schema (one object per line, ``metrics.jsonl``):
     ``{"event": "round",  "round": r, "metrics": {key: float|[float]}}``
     ``{"event": "block",  "round": r, "seconds": s, "rounds": n}``
@@ -80,7 +113,20 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Dict, Optional
 
-__all__ = ["TelemetryConfig", "resolve", "is_on", "merge_disjoint"]
+__all__ = ["TelemetryConfig", "resolve", "is_on", "merge_disjoint",
+           "LAYERS"]
+
+#: the layer scopes of ``repro.obs.profiling.layer``: name -> the top-level
+#: scope it nests in (None for the five top-level scopes)
+LAYERS: Dict[str, Optional[str]] = {
+    "chan_step": None,
+    "local_steps": None,
+    "ota_pack": None,
+    "ota_receive": None,
+    "ota_dual": None,
+    "penalty": "local_steps",
+    "ota_noise": "ota_receive",
+}
 
 
 @dataclasses.dataclass(frozen=True)

@@ -1,12 +1,18 @@
-"""Profiling hooks: trace annotations, wall-clock spans, compile reports.
+"""Profiling hooks: trace annotations, layer scopes, wall-clock spans,
+compile reports.
 
-Three independent pieces, all safe no-ops when profiling is off:
+Four independent pieces, all safe no-ops when profiling is off:
 
 * :func:`annotate` / :func:`trace_session` — ``jax.profiler`` named trace
   annotations and a start/stop trace context around a run.  A trace that
   cannot start or stop raises: ``--profile`` never exits 0 without one.
-* :class:`SpanTimer` — wall-clock spans (compile vs execute split, per-block
-  seconds) accumulated into a JSON-serialisable dict.
+* :func:`layer` — ``jax.named_scope`` over the vocabulary
+  ``repro.obs.LAYERS``: compile-time metadata on the step's HLO ops that
+  names the layer each op belongs to (no op, no run-time cost).
+* :class:`SpanTimer` — wall-clock spans (compile vs execute split, the
+  host's per-round phases) accumulated into a JSON-serialisable dict,
+  each span also a trace annotation, plus a count of backend compilations
+  after the first dispatch.
 * :func:`compile_report` — static analysis of a compiled module's optimized
   HLO via :mod:`repro.launch.hlo_analysis`: dispatch flops/bytes,
   per-collective byte/op counts, and the collective-permute reshard
@@ -17,10 +23,12 @@ from __future__ import annotations
 import contextlib
 import json
 import os
+import threading
 import time
 from typing import Any, Dict, Optional
 
-__all__ = ["annotate", "trace_session", "SpanTimer", "compile_report"]
+__all__ = ["annotate", "trace_session", "layer", "SpanTimer",
+           "compile_report"]
 
 
 @contextlib.contextmanager
@@ -53,18 +61,80 @@ def trace_session(trace_dir: Optional[str]):
         jax.profiler.stop_trace()
 
 
+#: the top-level layer scope open on this thread while a step is traced
+_LAYER = threading.local()
+
+
+def layer(name: str):
+    """``jax.named_scope(name)`` for a name of ``repro.obs.LAYERS``; usable
+    as a context manager or a decorator.
+
+    The scope nests as the vocabulary says: a top-level scope opened while
+    another top-level scope is open, or a nested scope outside its parent,
+    opens nothing, so an op carries at most one top-level scope.  Raises
+    ``ValueError`` on a name outside the vocabulary.
+    """
+    from repro.obs import LAYERS
+    if name not in LAYERS:
+        raise ValueError(f"unknown layer scope {name!r}; the vocabulary is "
+                         f"repro.obs.LAYERS: {sorted(LAYERS)}")
+    return _layer_scope(name, LAYERS[name])
+
+
+@contextlib.contextmanager
+def _layer_scope(name: str, parent: Optional[str]):
+    import jax
+    # a top-level scope (parent None) opens where no top-level scope is
+    # open; a nested one, inside its parent
+    if getattr(_LAYER, "top", None) != parent:
+        yield
+        return
+    if parent is None:
+        _LAYER.top = name
+    try:
+        with jax.named_scope(name):
+            yield
+    finally:
+        if parent is None:
+            _LAYER.top = None
+
+
+#: backend compilations in this process, counted by one jax.monitoring
+#: listener registered on first use
+_COMPILES = {"n": 0, "listening": False}
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def _on_event(event: str, *_args, **_kw) -> None:
+    if event == _COMPILE_EVENT:
+        _COMPILES["n"] += 1
+
+
+def _compiles() -> int:
+    if not _COMPILES["listening"]:
+        import jax
+        jax.monitoring.register_event_duration_secs_listener(_on_event)
+        _COMPILES["listening"] = True
+    return _COMPILES["n"]
+
+
 class SpanTimer:
-    """Named wall-clock spans, accumulated + counted.
+    """Named wall-clock spans, accumulated + counted, each one a
+    ``jax.profiler`` trace annotation of the same name.
 
     >>> t = SpanTimer()
-    >>> with t.span("execute"): run_block()
-    >>> t.summary()["execute"]["seconds"]
+    >>> with t.span("dispatch"): step(...)
+    >>> t.summary()["dispatch"]["seconds"]
+
+    ``compiles_after_first`` counts backend compilations after the first
+    ``dispatch`` span closed: a run whose step recompiles shows it there.
     """
 
     def __init__(self):
         self.spans: Dict[str, Dict[str, float]] = {}
         #: per-span list of individual durations (s/round series etc.)
         self.series: Dict[str, list] = {}
+        self._compiles_at_first: Optional[int] = None
 
     @contextlib.contextmanager
     def span(self, name: str):
@@ -78,12 +148,14 @@ class SpanTimer:
             s["seconds"] += dt
             s["count"] += 1.0
             self.series.setdefault(name, []).append(dt)
+            if name == "dispatch" and self._compiles_at_first is None:
+                self._compiles_at_first = _compiles()
 
-    def add(self, name: str, seconds: float) -> None:
-        s = self.spans.setdefault(name, {"seconds": 0.0, "count": 0.0})
-        s["seconds"] += float(seconds)
-        s["count"] += 1.0
-        self.series.setdefault(name, []).append(float(seconds))
+    @property
+    def compiles_after_first(self) -> int:
+        if self._compiles_at_first is None:
+            return 0
+        return _compiles() - self._compiles_at_first
 
     def summary(self) -> Dict[str, Dict[str, float]]:
         return {k: dict(v) for k, v in self.spans.items()}

@@ -47,6 +47,7 @@ from repro.phy import fading as _fading
 from repro.phy import geometry as _geo
 from repro.phy import population as _pop
 from repro.phy.geometry import GeometryConfig
+from repro.obs.profiling import layer
 
 Array = jax.Array
 
@@ -195,6 +196,7 @@ class Scenario:
         return self._assemble(kc, h_small, gain, shadow, pos, dest,
                               jnp.zeros((), jnp.int32), d)
 
+    @layer("chan_step")
     def step(self, key: Array, state: PhyState) -> PhyState:
         cfg = self.cfg
         if (cfg.coherence_iters >= STATIC_COHERENCE and self._plain_fading

@@ -64,6 +64,7 @@ from repro.core.tree_ota import (TreeChannel, TreeFLState, _zmap,
 from repro.models.registry import Model
 from repro.models.sharding import shard
 from repro.obs import merge_disjoint, resolve as resolve_telemetry
+from repro.obs.profiling import layer
 from repro.optim.optimizers import adam, sgd
 
 Array = jax.Array
@@ -387,9 +388,10 @@ def make_replicated(model: Model, flcfg: FLConfig, acfg: AdmmConfig,
             theta, opt_state = opt.update(g, opt_state, theta)
             return (theta, opt_state), jnp.mean(losses)
 
-        (theta, opt_state), losses = jax.lax.scan(
-            local_body, (theta_run, opt_run), None,
-            length=flcfg.local_steps)
+        with layer("local_steps"):
+            (theta, opt_state), losses = jax.lax.scan(
+                local_body, (theta_run, opt_run), None,
+                length=flcfg.local_steps)
 
         if shard_local:  # incl. scenarios: (W,) masks replicate over model
             Theta_f32, lam_new, m = ota_tree_round_shard_local(
@@ -685,8 +687,9 @@ def make_sketched(model: Model, flcfg: FLConfig, acfg: AdmmConfig,
                 lambda p, gg: p - flcfg.local_lr * gg.astype(p.dtype), theta, g)
             return theta, l
 
-        theta, losses = jax.lax.scan(body, Theta, None,
-                                     length=flcfg.local_steps)
+        with layer("local_steps"):
+            theta, losses = jax.lax.scan(body, Theta, None,
+                                         length=flcfg.local_steps)
         delta = jax.tree.map(
             lambda a, b_: (a - b_).astype(jnp.float32), theta, Theta)
         return delta, losses[-1]

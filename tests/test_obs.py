@@ -13,6 +13,10 @@
   raises on any collision between producer namespaces.
 * ``MetricsSink`` JSONL: one event per round, non-finite -> null, resumed
   runs append after a resume marker, and the CI linter accepts the result.
+* Layer scopes (``repro.obs.LAYERS``): ``layer`` refuses a name outside
+  the vocabulary and nests as it says; the trainer's compiled step carries
+  the scopes in its HLO metadata.  The launcher's ``--profile`` names each
+  round's host phases in the trace and counts recompilations.
 """
 import json
 import math
@@ -408,3 +412,115 @@ def test_launcher_run_dir_scan_logs_every_round(tmp_path):
     assert rep["rounds_per_dispatch"] == 2
     man = json.load(open(os.path.join(rd, "manifest.json")))
     assert man["telemetry"] is True and man["driver"] == "scan"
+
+
+def test_launcher_profile_names_host_spans(tmp_path):
+    """--profile: every round is a ``round`` trace step whose host phases
+    are named spans in the trace and in ``profile.json``, and the step
+    compiles once (no compilation after the first dispatch)."""
+    rd = str(tmp_path / "run")
+    p = _launch(tmp_path, "--rounds", "3", "--log-every", "1",
+                "--run-dir", rd, "--profile")
+    assert p.returncode == 0, p.stderr[-2000:]
+    prof = json.load(open(os.path.join(rd, "profile.json")))
+    phases = {"batch", "dispatch", "readback", "log", "checkpoint"}
+    assert phases | {"execute", "compile"} == set(prof["spans"])
+    assert all(prof["spans"][k]["count"] == 3 for k in phases | {"execute"})
+    assert prof["compiles_after_first"] == 0
+    assert "compiles_after_first=0" in p.stdout
+    from jax.profiler import ProfileData
+    import glob
+    (xplane,) = glob.glob(os.path.join(rd, "trace", "**", "*.xplane.pb"),
+                          recursive=True)
+    names = {e.name for plane in ProfileData.from_file(xplane).planes
+             for line in plane.lines for e in line.events}
+    assert phases | {"round"} <= names
+
+
+# ---------------------------------------------------------------------------
+# layer scopes: one vocabulary, opened where the work happens
+# ---------------------------------------------------------------------------
+
+def _carries(op_name, scope):
+    import re
+    return re.search(r"(^|[/(])" + scope + r"([)/]|$)", op_name) is not None
+
+
+def _op_names(fn, *args):
+    import re
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return re.findall(r'op_name="([^"]*)"', text)
+
+
+def test_layer_refuses_unknown_name():
+    from repro.obs.profiling import layer
+    with pytest.raises(ValueError, match="LAYERS"):
+        layer("attention")
+    assert set(obs.LAYERS) == {"chan_step", "local_steps", "ota_pack",
+                               "ota_receive", "ota_dual", "penalty",
+                               "ota_noise"}
+
+
+def test_layer_nests_as_the_vocabulary_says():
+    """A top-level scope inside another, or a nested scope outside its
+    parent, opens nothing: an op carries one top-level scope at most."""
+    from repro.obs.profiling import layer
+
+    def f(x):
+        with layer("ota_receive"):
+            with layer("ota_pack"):          # owned by the receive
+                y = jnp.sin(x)
+            with layer("ota_noise"):
+                y = y * jnp.cos(x)
+        with layer("penalty"):               # outside local_steps
+            y = jnp.tanh(y) + 1.0
+        return y
+
+    ops = _op_names(f, jnp.ones(8))
+    assert any(_carries(o, "ota_noise") and _carries(o, "ota_receive")
+               for o in ops)
+    assert not any(_carries(o, "ota_pack") or _carries(o, "penalty")
+                   for o in ops)
+
+
+@pytest.mark.parametrize("mode,kw,missing", [
+    ("replicated", {}, set()),
+    ("sketched", {}, {"penalty"}),
+    ("replicated", {"packed_uplink": False}, {"ota_pack"}),
+])
+def test_trainer_step_carries_layer_scopes(mode, kw, missing):
+    """The reduced granite trainer's compiled step names its layers in the
+    HLO metadata: every scope of the vocabulary (the sketched path has no
+    penalty, the leafwise layout no packing), none nested in another
+    top-level scope."""
+    from repro.core.admm import AdmmConfig
+    from repro.core.channel import ChannelConfig
+    from repro.models import get_model
+    from repro.train.llm_trainer import FLConfig, make_fl_train
+    m = get_model("granite-8b", reduced=True)
+    batch = {"tokens": jax.random.randint(KEY, (2, 1, 16), 0,
+                                          m.cfg.vocab_size)}
+    init_fn, step = make_fl_train(
+        m, FLConfig(mode=mode, n_workers=2, local_steps=2, sketch_ratio=16,
+                    **kw),
+        AdmmConfig(rho=0.5), ChannelConfig(n_workers=2, snr_db=5.0))
+    ops = _op_names(step, init_fn(KEY), batch, KEY)
+    found = {s for s in obs.LAYERS if any(_carries(o, s) for o in ops)}
+    assert found == set(obs.LAYERS) - missing
+    top = [s for s, parent in obs.LAYERS.items() if parent is None]
+    assert all(sum(_carries(o, s) for s in top) <= 1 for o in ops)
+
+
+def test_span_timer_counts_compiles_after_first_dispatch():
+    from repro.obs.profiling import SpanTimer
+    t = SpanTimer()
+    f = jax.jit(lambda x: x * 3.0 + 1.0)
+    x5, x7 = np.ones(5, np.float32), np.ones(7, np.float32)
+    with t.span("dispatch"):
+        f(x5).block_until_ready()            # compiles: before the count
+    with t.span("dispatch"):
+        f(x5).block_until_ready()
+    assert t.compiles_after_first == 0
+    f(x7).block_until_ready()                # a new shape recompiles
+    assert t.compiles_after_first == 1
+    assert t.summary()["dispatch"]["count"] == 2
