@@ -692,7 +692,7 @@ def ota_round_fused(theta: Array, lam: Complex, h: Complex, key: Array,
 
 def autotune_ota_round(W: int, d: int, ccfg: Optional[ChannelConfig] = None,
                        *, rho: float = 1.0,
-                       block_cols_grid=(256, 512, 1024, 2048),
+                       block_cols_grid: Optional[Tuple] = None,
                        worker_chunks=(0, 8, 32),
                        iters: int = 10, backend: Optional[str] = None,
                        seed: int = 0) -> dict:
@@ -703,9 +703,12 @@ def autotune_ota_round(W: int, d: int, ccfg: Optional[ChannelConfig] = None,
     planes and returns ``{"best": {...}, "table": [...]}``.  ``block_cols``
     only reaches the pallas kernels, so on the jnp backend the sweep
     degenerates to worker_chunk alone (one block_cols row is kept).  The
+    default grid is the kernels' own VMEM-sized tile (``None``) and the
+    powers of two from 256 lanes below it, so the sweep never picks a
+    narrower tile than the default unless it measured one faster.  The
     winning config maps 1:1 onto the env knobs
-    (``REPRO_OTA_BLOCK_COLS`` / ``REPRO_OTA_WORKER_CHUNK``) and the
-    ``FLConfig``/CLI fields.
+    (``REPRO_OTA_BLOCK_COLS`` / ``REPRO_OTA_WORKER_CHUNK``; ``None``
+    leaves the first unset) and the ``FLConfig``/CLI fields.
     """
     import time
 
@@ -718,6 +721,11 @@ def autotune_ota_round(W: int, d: int, ccfg: Optional[ChannelConfig] = None,
     lam = rayleigh(kl, (W, d))
     h = rayleigh(kh, (W, d))
 
+    if block_cols_grid is None:
+        from repro.kernels.ota import vmem_block_cols
+        widest = min(vmem_block_cols(W, 5), d)
+        block_cols_grid = (None,) + tuple(
+            256 << k for k in range(16) if 256 << k < widest)
     if resolve_backend(backend) != "pallas":
         block_cols_grid = block_cols_grid[:1]
     table = []
@@ -733,7 +741,8 @@ def autotune_ota_round(W: int, d: int, ccfg: Optional[ChannelConfig] = None,
                 jax.block_until_ready(fn(theta, lam, h, kr))
                 ts.append(time.perf_counter() - t0)
             ts.sort()
-            table.append({"block_cols": int(bc), "worker_chunk": int(wc),
+            table.append({"block_cols": None if bc is None else int(bc),
+                          "worker_chunk": int(wc),
                           "us": 1e6 * ts[len(ts) // 2]})
     best = min(table, key=lambda r: r["us"])
     return {"best": best, "table": table}

@@ -13,8 +13,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.ota import (LANE, _block_cols, _block_rows, _grid_spec,
-                               _pad_2d, _pad_lanes, _rows_for)
+from repro.kernels.ota import (LANE, _block_cols, _block_rows, _col_grid,
+                               _grid_spec, _pad_2d, _rows_for)
 
 Array = jax.Array
 
@@ -61,28 +61,27 @@ def admm_dual_update(lam_re: Array, lam_im: Array, h_re: Array, h_im: Array,
     W = shape[0] if len(shape) == 2 else 1
     n = lam_re.size // W
     has_noise = noise_re is not None
-    block_cols = _block_cols(block_cols, W, 8 + has_noise)
-    cols = -(-n // block_cols) * block_cols
+    block_cols = _block_cols(block_cols, W, 8 + has_noise, n)
 
     def plane(x: Array, rows: int = W) -> Array:
-        return _pad_lanes(x.astype(jnp.float32).reshape(rows, n), cols)
+        return x.astype(jnp.float32).reshape(rows, n)
 
     args = [plane(lam_re), plane(lam_im), plane(h_re), plane(h_im),
-            _pad_lanes(theta.reshape(W, n), cols), plane(Theta, 1)]
+            theta.reshape(W, n), plane(Theta, 1)]
     if has_noise:
         args.append(plane(noise_re))
     wspec = pl.BlockSpec((W, block_cols), lambda i: (0, i))
     rspec = pl.BlockSpec((1, block_cols), lambda i: (0, i))
     ore, oim = pl.pallas_call(
         functools.partial(_dual_kernel, rho=float(rho), has_noise=has_noise),
-        grid=(cols // block_cols,),
+        grid=_col_grid("admm_dual_update", W, n, block_cols, args),
         in_specs=[wspec] * 5 + [rspec] + [wspec] * has_noise,
         out_specs=[wspec, wspec],
-        out_shape=[jax.ShapeDtypeStruct((W, cols), jnp.float32)] * 2,
+        out_shape=[jax.ShapeDtypeStruct((W, n), jnp.float32)] * 2,
         input_output_aliases={0: 0, 1: 1},
         interpret=interpret,
     )(*args)
-    return ore[:, :n].reshape(shape), oim[:, :n].reshape(shape)
+    return ore.reshape(shape), oim.reshape(shape)
 
 
 def admm_flip_lambda(grad: Array, theta: Array, Theta_prev: Array,

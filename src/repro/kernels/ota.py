@@ -7,9 +7,15 @@ resources).  Fusing the complex arithmetic into one pass halves the HBM
 traffic versus the 4–5 elementwise HLOs XLA would otherwise schedule
 (conj, mul, add, div, select).
 
-Layout: flat f32 planes reshaped to (rows, 1024) = 8×128-aligned VMEM tiles.
-Complex values travel as separate re/im planes (no complex dtype on the
-TPU VPU).
+Layout: the flat elementwise kernels reshape their f32 planes to
+(rows, 1024) = 8×128-aligned VMEM tiles.  The worker-grid kernels (the
+receive here, ``ota_round``, ``admm_update.admm_dual_update``,
+``phy_channel.ota_receive_masked``) keep their ``(W, d)`` planes as laid
+out and walk the ``d`` axis in column tiles as wide as the VMEM budget
+allows (:func:`_block_cols`), tens of thousands of lanes at small W: each
+grid step has a fixed cost, so a narrow tile spends the launch on steps.
+The last tile may overhang ``d``; no plane is padded in HBM.  Complex
+values travel as separate re/im planes (no complex dtype on the TPU VPU).
 """
 from __future__ import annotations
 
@@ -25,7 +31,6 @@ Array = jax.Array
 
 LANE = 1024               # 8 sublanes x 128 lanes
 DEFAULT_BLOCK_ROWS = 256  # 256*1024*4B = 1 MiB per f32 operand tile
-MAX_BLOCK_COLS = 1024
 
 #: VMEM the kernels may fill with their pipelined tiles: under the 16 MiB
 #: scoped-VMEM default of v5e (the smallest of the TPU generations), with
@@ -50,29 +55,48 @@ def _block_rows(block_rows: Optional[int], n_planes: int) -> int:
                       VMEM_TILE_BUDGET // per_row // 8 * 8))
 
 
-def _block_cols(block_cols: Optional[int], n_workers: int,
-                n_planes: int) -> int:
-    """Column tile of a worker-grid kernel: explicit arg, else
-    ``REPRO_OTA_BLOCK_COLS``, else the widest multiple of 128 lanes (at
-    most :data:`MAX_BLOCK_COLS`) whose working set fits
-    :data:`VMEM_TILE_BUDGET`.
+def vmem_block_cols(n_workers: int, n_planes: int) -> int:
+    """The widest column tile, a multiple of 128 lanes, whose working set
+    fits :data:`VMEM_TILE_BUDGET`.
 
     ``n_planes`` counts the ``(W, block_cols)`` operands and results of the
     launch.  Each is double-buffered by the pipeline, and the body holds
     about as many ``(W, block_cols)`` temporaries again, so the working set
     is ``3 · n_planes · W₈ · block_cols · 4`` bytes, ``W₈`` being ``W``
-    rounded up to the 8 sublanes of a vreg.
+    rounded up to the 8 sublanes of a vreg.  No lane cap: the budget alone
+    binds (16,384–32,768 lanes at W ≤ 8, 768 at W = 256).
     """
-    if block_cols is not None:
-        return block_cols
-    from repro import optflags
-    env = optflags.ota_block_cols()
-    if env is not None:
-        return env
     w8 = -(-n_workers // 8) * 8
     per_col = 3 * n_planes * w8 * 4
-    return max(128, min(MAX_BLOCK_COLS,
-                        VMEM_TILE_BUDGET // per_col // 128 * 128))
+    return max(128, VMEM_TILE_BUDGET // per_col // 128 * 128)
+
+
+def _block_cols(block_cols: Optional[int], n_workers: int, n_planes: int,
+                n: int) -> int:
+    """Column tile of a worker-grid kernel over ``n`` columns: explicit arg,
+    else ``REPRO_OTA_BLOCK_COLS``, else :func:`vmem_block_cols`; a tile
+    as wide as ``n`` or wider becomes one full-width block."""
+    if block_cols is None:
+        from repro import optflags
+        block_cols = optflags.ota_block_cols()
+    if block_cols is None:
+        block_cols = vmem_block_cols(n_workers, n_planes)
+    return min(block_cols, n)
+
+
+def _col_grid(kernel: str, n_workers: int, n: int, block_cols: int,
+              planes) -> Tuple[int]:
+    """Grid of a worker-grid launch: ``cdiv(n, block_cols)`` column steps,
+    the last one overhanging ``n`` (Pallas reads it padded and drops the
+    overhanging writes).  The launch is noted in the open
+    ``repro.obs.profiling.grid_launches`` record, with the columns its
+    ``planes`` (the operands walked by the grid) hold beyond ``n``."""
+    from repro.obs.profiling import record_grid_launch
+    steps = pl.cdiv(n, block_cols)
+    record_grid_launch(kernel, workers=n_workers, n=n,
+                       block_cols=block_cols, steps=steps,
+                       pad_cols=max(p.shape[-1] for p in planes) - n)
+    return (steps,)
 
 
 def _mod_kernel(theta_ref, lre_ref, lim_ref, hre_ref, him_ref,
@@ -121,18 +145,12 @@ def _grid_spec(n_inputs: int, rows: int, block_rows: int):
     return grid, [spec] * n_inputs, spec
 
 
-def _pad_lanes(x: Array, cols: int) -> Array:
-    """Zero-pad the last dim of ``x`` to ``cols``; no copy when it is that
-    wide already."""
-    pad = cols - x.shape[-1]
-    if not pad:
-        return x
-    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
-
-
 def _pad_2d(x: Array, rows: int) -> Array:
-    """Flat ``x`` as ``(rows, LANE)``."""
-    return _pad_lanes(x.reshape(-1), rows * LANE).reshape(rows, LANE)
+    """Flat ``x`` as ``(rows, LANE)``, zero-padded; no copy when it fills
+    them already."""
+    x = x.reshape(-1)
+    pad = rows * LANE - x.size
+    return (jnp.pad(x, (0, pad)) if pad else x).reshape(rows, LANE)
 
 
 def _rows_for(n: int) -> int:
@@ -198,21 +216,20 @@ def ota_demodulate_dyn(y_re: Array, noise_re: Array, sumh2: Array,
     layout the round's stats kernel emits ``y_re``/``Σ|h|²`` in, so no
     operand is re-tiled in HBM on the way in."""
     n = y_re.size
-    block_cols = _block_cols(block_cols, 1, 4)
-    cols = -(-n // block_cols) * block_cols
-    args = [_pad_lanes(a.astype(jnp.float32).reshape(1, n), cols)
+    block_cols = _block_cols(block_cols, 1, 4, n)
+    args = [a.astype(jnp.float32).reshape(1, n)
             for a in (y_re, noise_re, sumh2)]
     ia = jnp.asarray(inv_alpha, jnp.float32).reshape(1)
     spec = pl.BlockSpec((1, block_cols), lambda i: (0, i))
     out = pl.pallas_call(
         _demod_dyn_kernel,
-        grid=(cols // block_cols,),
+        grid=_col_grid("ota_demodulate_dyn", 1, n, block_cols, args),
         in_specs=[_scalar_spec()] + [spec] * 3,
         out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((1, cols), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
         interpret=interpret,
     )(ia, *args)
-    return out[0, :n].reshape(y_re.shape)
+    return out.reshape(y_re.shape)
 
 
 def ota_accumulate(y_re: Array, sumh2: Array, s_re: Array, s_im: Array,
@@ -264,24 +281,18 @@ def ota_receive(s_re: Array, s_im: Array, h_re: Array, h_im: Array,
     local, so the whole receive stays one kernel per shard).
     """
     W, n = s_re.shape
-    block_cols = _block_cols(block_cols, W, 4)
-    cols = -(-n // block_cols) * block_cols
-
-    def padw(x: Array) -> Array:
-        return jnp.pad(x.astype(jnp.float32), ((0, 0), (0, cols - n)))
-
-    args = [padw(a) for a in (s_re, s_im, h_re, h_im)]
-    nz = jnp.pad(noise_re.astype(jnp.float32), (0, cols - n)).reshape(1, cols)
+    block_cols = _block_cols(block_cols, W, 4, n)
+    args = [a.astype(jnp.float32) for a in (s_re, s_im, h_re, h_im)]
+    nz = noise_re.astype(jnp.float32).reshape(1, n)
     ia = jnp.asarray(inv_alpha, jnp.float32).reshape(1)
-    grid = (cols // block_cols,)
     wspec = pl.BlockSpec((W, block_cols), lambda i: (0, i))
     rspec = pl.BlockSpec((1, block_cols), lambda i: (0, i))
     out = pl.pallas_call(
         _receive_kernel,
-        grid=grid,
+        grid=_col_grid("ota_receive", W, n, block_cols, args + [nz]),
         in_specs=[_scalar_spec()] + [wspec] * 4 + [rspec],
         out_specs=rspec,
-        out_shape=jax.ShapeDtypeStruct((1, cols), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
         interpret=interpret,
     )(ia, *args, nz)
-    return out.reshape(-1)[:n]
+    return out.reshape(-1)

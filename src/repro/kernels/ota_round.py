@@ -30,9 +30,13 @@ every grid step revisits, so the stats kernel's grid axis is sequential
 energies/α agree to float tolerance, not bitwise; the noise-free Θ stays
 bitwise regardless (zero noise × any α).
 
-Layout matches the kernel set: flat f32 planes on a column grid of
-``block_cols`` lanes (sized from W by ``kernels/ota._block_cols`` so the
-working set fits VMEM); runtime scalars ride in SMEM.
+Layout matches the kernel set: the ``(W, d)`` f32 planes as laid out, on a
+column grid of ``block_cols`` lanes, the widest tile whose working set fits
+VMEM (``kernels/ota._block_cols``: 26,112 lanes for the stats kernel at
+W = 2, 768 at W = 256).  The last tile may overhang ``d``: Pallas reads it
+padded and drops the overhanging writes, and the energy sum, the one
+reduction across columns, masks those columns out.  No plane is padded in
+HBM.  Runtime scalars ride in SMEM.
 """
 from __future__ import annotations
 
@@ -44,7 +48,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.ota import _block_cols
+from repro.kernels.ota import _block_cols, _col_grid
 
 Array = jax.Array
 
@@ -55,7 +59,7 @@ def _scalar_spec(n: int = 1):
 
 
 def _round_kernel(*refs, inv_rho: float, has_mask: bool, has_htx: bool,
-                  has_chan: bool, emit_theta: bool):
+                  has_chan: bool, emit_theta: bool, n: int, block_cols: int):
     """Shared body of the stats/theta round kernels.
 
     Ref order (inputs): [ia (SMEM) if emit_theta] [chan params (SMEM) if
@@ -103,7 +107,13 @@ def _round_kernel(*refs, inv_rho: float, has_mask: bool, has_htx: bool,
         def _init():
             e_ref[...] = jnp.zeros_like(e_ref)
 
-        e_ref[...] += jnp.sum(sre * sre + sim * sim, axis=1, keepdims=True)
+        e2 = sre * sre + sim * sim
+        if n % block_cols:
+            # the last block overhangs the n columns; what Pallas read
+            # past them must not reach the sum
+            col = jax.lax.broadcasted_iota(jnp.int32, e2.shape, 1)
+            e2 = jnp.where(col < n - pl.program_id(0) * block_cols, e2, 0.0)
+        e_ref[...] += jnp.sum(e2, axis=1, keepdims=True)
 
     if has_mask:
         active = m_ref[...] != 0.0
@@ -132,18 +142,17 @@ def _round_call(theta, lam_re, lam_im, h_re, h_im, rho, *, mask, htx, chan,
     # (W, block_cols) planes of the launch: mask, the five round planes,
     # CSI and innovations in, the stepped channel out
     block_cols = _block_cols(block_cols, W,
-                             has_mask + 5 + 2 * has_htx + 4 * has_chan)
-    cols = -(-n // block_cols) * block_cols
+                             has_mask + 5 + 2 * has_htx + 4 * has_chan, n)
 
-    def padw(x: Array) -> Array:
-        return jnp.pad(x.astype(jnp.float32), ((0, 0), (0, cols - n)))
+    def f32(x: Array) -> Array:
+        return x.astype(jnp.float32)
 
     wspec = pl.BlockSpec((W, block_cols), lambda i: (0, i))
     mspec = pl.BlockSpec((W, block_cols), lambda i: (0, 0))
     rspec = pl.BlockSpec((1, block_cols), lambda i: (0, i))
     espec = pl.BlockSpec((W, 1), lambda i: (0, 0))
-    wplane = jax.ShapeDtypeStruct((W, cols), jnp.float32)
-    rplane = jax.ShapeDtypeStruct((1, cols), jnp.float32)
+    wplane = jax.ShapeDtypeStruct((W, n), jnp.float32)
+    rplane = jax.ShapeDtypeStruct((1, n), jnp.float32)
 
     ops, in_specs = [], []
     if emit_theta:
@@ -160,17 +169,15 @@ def _round_call(theta, lam_re, lam_im, h_re, h_im, rho, *, mask, htx, chan,
         ops.append(jnp.broadcast_to(mask.astype(jnp.float32)[:, None],
                                     (W, block_cols)))
         in_specs.append(mspec)
-    ops += [padw(a) for a in (theta, lam_re, lam_im, h_re, h_im)]
-    in_specs += [wspec] * 5
+    planes = [f32(a) for a in (theta, lam_re, lam_im, h_re, h_im)]
     if has_htx:
-        ops += [padw(htx[0]), padw(htx[1])]
-        in_specs += [wspec, wspec]
+        planes += [f32(htx[0]), f32(htx[1])]
     if has_chan:
-        ops += [padw(w_re), padw(w_im)]
-        in_specs += [wspec, wspec]
+        planes += [f32(w_re), f32(w_im)]
+    ops += planes
+    in_specs += [wspec] * len(planes)
     if emit_theta:
-        ops.append(jnp.pad(noise_re.astype(jnp.float32),
-                           (0, cols - n)).reshape(1, cols))
+        ops.append(f32(noise_re).reshape(1, n))
         in_specs.append(rspec)
 
     if emit_theta:
@@ -185,10 +192,12 @@ def _round_call(theta, lam_re, lam_im, h_re, h_im, rho, *, mask, htx, chan,
 
     kernel = functools.partial(
         _round_kernel, inv_rho=1.0 / rho, has_mask=has_mask,
-        has_htx=has_htx, has_chan=has_chan, emit_theta=emit_theta)
+        has_htx=has_htx, has_chan=has_chan, emit_theta=emit_theta, n=n,
+        block_cols=block_cols)
     outs = pl.pallas_call(
         kernel,
-        grid=(cols // block_cols,),
+        grid=_col_grid("ota_round_theta" if emit_theta else "ota_round_stats",
+                       W, n, block_cols, planes),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
@@ -200,12 +209,12 @@ def _round_call(theta, lam_re, lam_im, h_re, h_im, rho, *, mask, htx, chan,
 
     it = iter(outs)
     if emit_theta:
-        res = (next(it).reshape(-1)[:n],)
+        res = (next(it).reshape(-1),)
     else:
         y, p2, e = next(it), next(it), next(it)
-        res = (y.reshape(-1)[:n], p2.reshape(-1)[:n], e.reshape(W))
+        res = (y.reshape(-1), p2.reshape(-1), e.reshape(W))
     if has_chan:
-        res += (next(it)[:, :n], next(it)[:, :n])
+        res += (next(it), next(it))
     return res
 
 

@@ -16,8 +16,10 @@ transport kernels (``kernels/ota.py``):
   superposition), then superpose → matched-filter → demodulate exactly like
   ``kernels/ota.ota_receive``.
 
-Layout matches the rest of the kernel set: flat f32 planes reshaped to
-(rows, 1024) 8×128-aligned VMEM tiles; runtime scalars ride in SMEM.
+Layout matches the rest of the kernel set (``kernels/ota.py``): the fading
+step's flat f32 planes reshaped to (rows, 1024) 8×128-aligned VMEM tiles,
+the receive's ``(W, d)`` planes walked in VMEM-sized column tiles; runtime
+scalars ride in SMEM.
 """
 from __future__ import annotations
 
@@ -30,8 +32,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 # one tiling scheme for the whole OTA/phy kernel set — a layout change in
 # kernels/ota.py (lane width, padding rule) must reach these kernels too
-from repro.kernels.ota import (LANE, _block_cols, _block_rows, _grid_spec,
-                               _pad_2d, _rows_for)
+from repro.kernels.ota import (LANE, _block_cols, _block_rows, _col_grid,
+                               _grid_spec, _pad_2d, _rows_for)
 
 Array = jax.Array
 
@@ -117,26 +119,20 @@ def ota_receive_masked(s_re: Array, s_im: Array, h_re: Array, h_im: Array,
     it is independent of how the packed axis is split.
     """
     W, n = s_re.shape
-    block_cols = _block_cols(block_cols, W, 5)
-    cols = -(-n // block_cols) * block_cols
-
-    def padw(x: Array) -> Array:
-        return jnp.pad(x.astype(jnp.float32), ((0, 0), (0, cols - n)))
-
-    args = [padw(a) for a in (s_re, s_im, h_re, h_im)]
+    block_cols = _block_cols(block_cols, W, 5, n)
+    args = [a.astype(jnp.float32) for a in (s_re, s_im, h_re, h_im)]
     m = jnp.broadcast_to(mask.astype(jnp.float32)[:, None], (W, block_cols))
-    nz = jnp.pad(noise_re.astype(jnp.float32), (0, cols - n)).reshape(1, cols)
+    nz = noise_re.astype(jnp.float32).reshape(1, n)
     ia = jnp.asarray(inv_alpha, jnp.float32).reshape(1)
-    grid = (cols // block_cols,)
     wspec = pl.BlockSpec((W, block_cols), lambda i: (0, i))
     mspec = pl.BlockSpec((W, block_cols), lambda i: (0, 0))
     rspec = pl.BlockSpec((1, block_cols), lambda i: (0, i))
     out = pl.pallas_call(
         _receive_masked_kernel,
-        grid=grid,
+        grid=_col_grid("ota_receive_masked", W, n, block_cols, args + [nz]),
         in_specs=[_scalar_spec(1), mspec] + [wspec] * 4 + [rspec],
         out_specs=rspec,
-        out_shape=jax.ShapeDtypeStruct((1, cols), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
         interpret=interpret,
     )(ia, m, *args, nz)
-    return out.reshape(-1)[:n]
+    return out.reshape(-1)

@@ -167,7 +167,9 @@ def main() -> None:
                          "compile, execute, and each round's batch, "
                          "dispatch, readback, log and checkpoint (also "
                          "trace annotations inside a 'round' trace step), "
-                         "and compilations after the first dispatch")
+                         "compilations after the first dispatch, and the "
+                         "OTA kernels' grid steps and padded columns per "
+                         "round (ota_grid_steps, ota_pad_cols)")
     args = ap.parse_args()
     enable_compile_cache()
 
@@ -283,7 +285,7 @@ def main() -> None:
             best = res["best"]
             # knobs are read lazily at trace time, so the envs land before
             # the first compile; explicit flags win over the autotuner
-            if args.ota_block_cols is None:
+            if args.ota_block_cols is None and best["block_cols"]:
                 os.environ["REPRO_OTA_BLOCK_COLS"] = str(best["block_cols"])
             if args.ota_worker_chunk is None:
                 os.environ["REPRO_OTA_WORKER_CHUNK"] = \
@@ -347,14 +349,20 @@ def main() -> None:
         return timer.span(name) if timer is not None \
             else contextlib.nullcontext()
 
+    #: the worker-grid kernel launches traced into one round's step
+    step_launches = []
+
     def aot_compile(jitted, sample_args, rounds_per_dispatch):
         """AOT lower + compile (timed, so the compile/execute split is
-        real) and write ``compile_report.json`` from the optimized HLO."""
+        real), note the step's worker-grid launches, and write
+        ``compile_report.json`` from the optimized HLO."""
         if timer is None:
             return jitted
-        from repro.obs.profiling import compile_report
+        from repro.obs.profiling import compile_report, grid_launches
         t_l = time.perf_counter()
-        lowered = jitted.lower(*sample_args)
+        with grid_launches() as launches:
+            lowered = jitted.lower(*sample_args)
+        step_launches[:] = launches
         t_c = time.perf_counter()
         with timer.span("compile"):
             compiled = lowered.compile()
@@ -464,16 +472,20 @@ def main() -> None:
     if timer is not None:
         summ = timer.summary()
         recompiles = timer.compiles_after_first
+        # per round: the grid steps of the OTA kernels' column grids, and
+        # the columns their planes were padded by in HBM
+        grid = {"ota_grid_steps": sum(l["steps"] for l in step_launches),
+                "ota_pad_cols": sum(l["pad_cols"] for l in step_launches)}
         if args.run_dir:
             with open(os.path.join(args.run_dir, "profile.json"), "w") as f:
                 json.dump({"spans": summ, "series": timer.series,
-                           "compiles_after_first": recompiles}, f,
+                           "compiles_after_first": recompiles, **grid}, f,
                           indent=2, sort_keys=True)
                 f.write("\n")
         parts = ", ".join(f"{k}={v['seconds']:.2f}s/{int(v['count'])}x"
                           for k, v in sorted(summ.items()))
-        print(f"profile: {parts}, compiles_after_first={recompiles}",
-              flush=True)
+        print(f"profile: {parts}, compiles_after_first={recompiles}, "
+              + ", ".join(f"{k}={v}" for k, v in grid.items()), flush=True)
 
     if args.checkpoint:
         Theta = st.Theta

@@ -1,7 +1,7 @@
 """Profiling hooks: trace annotations, layer scopes, wall-clock spans,
 compile reports.
 
-Four independent pieces, all safe no-ops when profiling is off:
+Five independent pieces, all safe no-ops when profiling is off:
 
 * :func:`annotate` / :func:`trace_session` — ``jax.profiler`` named trace
   annotations and a start/stop trace context around a run.  A trace that
@@ -9,6 +9,10 @@ Four independent pieces, all safe no-ops when profiling is off:
 * :func:`layer` — ``jax.named_scope`` over the vocabulary
   ``repro.obs.LAYERS``: compile-time metadata on the step's HLO ops that
   names the layer each op belongs to (no op, no run-time cost).
+* :func:`grid_launches` / :func:`record_grid_launch` — a trace-time record
+  of the worker-grid Pallas launches (``kernels/ota``): each launch's
+  kernel, W, columns, column tile, grid steps and the columns it padded
+  in HBM (no op, no run-time cost).
 * :class:`SpanTimer` — wall-clock spans (compile vs execute split, the
   host's per-round phases) accumulated into a JSON-serialisable dict,
   each span also a trace annotation, plus a count of backend compilations
@@ -27,8 +31,8 @@ import threading
 import time
 from typing import Any, Dict, Optional
 
-__all__ = ["annotate", "trace_session", "layer", "SpanTimer",
-           "compile_report"]
+__all__ = ["annotate", "trace_session", "layer", "grid_launches",
+           "record_grid_launch", "SpanTimer", "compile_report"]
 
 
 @contextlib.contextmanager
@@ -97,6 +101,42 @@ def _layer_scope(name: str, parent: Optional[str]):
     finally:
         if parent is None:
             _LAYER.top = None
+
+
+#: the open ``grid_launches`` record on this thread, or None
+_LAUNCHES = threading.local()
+
+
+@contextlib.contextmanager
+def grid_launches():
+    """Collect the worker-grid kernel launches traced inside the block.
+
+    Yields a list that :func:`record_grid_launch` appends one dict to per
+    launch traced on this thread while the block is open: ``kernel``,
+    ``workers``, ``n`` (columns), ``block_cols``, ``steps`` (grid steps)
+    and ``pad_cols`` (columns the launch's planes were padded by in HBM).
+    A record opened inside another passes its launches on to it.
+    """
+    outer = getattr(_LAUNCHES, "log", None)
+    log: list = []
+    _LAUNCHES.log = log
+    try:
+        yield log
+    finally:
+        _LAUNCHES.log = outer
+        if outer is not None:
+            outer.extend(log)
+
+
+def record_grid_launch(kernel: str, *, workers: int, n: int,
+                       block_cols: int, steps: int, pad_cols: int) -> None:
+    """Note one worker-grid launch in the open :func:`grid_launches` record;
+    nothing when none is open."""
+    log = getattr(_LAUNCHES, "log", None)
+    if log is not None:
+        log.append({"kernel": kernel, "workers": workers, "n": n,
+                    "block_cols": block_cols, "steps": steps,
+                    "pad_cols": pad_cols})
 
 
 #: backend compilations in this process, counted by one jax.monitoring

@@ -16,7 +16,8 @@
 * Layer scopes (``repro.obs.LAYERS``): ``layer`` refuses a name outside
   the vocabulary and nests as it says; the trainer's compiled step carries
   the scopes in its HLO metadata.  The launcher's ``--profile`` names each
-  round's host phases in the trace and counts recompilations.
+  round's host phases in the trace, counts recompilations, and counts the
+  OTA kernels' grid steps and padded columns per round.
 """
 import json
 import math
@@ -435,6 +436,28 @@ def test_launcher_profile_names_host_spans(tmp_path):
     names = {e.name for plane in ProfileData.from_file(xplane).planes
              for line in plane.lines for e in line.events}
     assert phases | {"round"} <= names
+
+
+def test_launcher_profile_counts_ota_grid_steps(tmp_path):
+    """--profile on the packed round with the Pallas kernels: the profile
+    line and ``profile.json`` give the round's grid steps, each kernel's
+    cdiv(D, tile), and no padded column."""
+    import math
+    from repro.kernels.ota import vmem_block_cols
+    from repro.models import get_model
+    shapes = jax.eval_shape(get_model("granite-8b", reduced=True).init, KEY)
+    d = sum(math.prod(l.shape) for l in jax.tree.leaves(shapes))
+    # the round's stats (W=2, 5 planes), demodulate (W=1, 4) and dual
+    # (W=2, 8) kernels
+    steps = sum(-(-d // vmem_block_cols(w, p))
+                for w, p in ((2, 5), (1, 4), (2, 8)))
+    rd = str(tmp_path / "run")
+    p = _launch(tmp_path, "--rounds", "1", "--run-dir", rd, "--profile",
+                "--backend", "pallas")
+    assert p.returncode == 0, p.stderr[-2000:]
+    prof = json.load(open(os.path.join(rd, "profile.json")))
+    assert (prof["ota_grid_steps"], prof["ota_pad_cols"]) == (steps, 0)
+    assert f"ota_grid_steps={steps}, ota_pad_cols=0" in p.stdout
 
 
 # ---------------------------------------------------------------------------

@@ -6,7 +6,9 @@ block shape Mosaic refuses, an op it cannot lower, a kernel that needs more
 VMEM than the chip allows.  Each test compiles one kernel at the shapes of
 ``chip_smoke.py``'s main phase (granite-8b at published widths, one layer, a
 6,144-row vocabulary slice, W=2, sequence 2,048) and checks that the
-program holds a Mosaic kernel (``tpu_custom_call``).  Nothing runs.
+program holds a Mosaic kernel (``tpu_custom_call``); the OTA round's
+kernels also compile at their default column tile, which does not divide D,
+with no temp bytes: no plane is padded or copied.  Nothing runs.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and pytest-xdist workers all
@@ -22,7 +24,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import admm_update, flash_attention, linear_scan
-from repro.kernels import ota_round, phy_population
+from repro.kernels import ota, ota_round, phy_population
 
 #: packed parameter count of the main phase's model (granite-8b, 1 layer,
 #: vocab 6,144): tests/test_tpu_compile.py::test_main_phase_dim pins it
@@ -46,9 +48,17 @@ def _spec(sharding, shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _assert_mosaic(fn, *args):
-    compiled = jax.jit(fn).lower(*args).compile()
+def _assert_mosaic(fn, *args, donate=()):
+    compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _assert_no_plane_copy(compiled):
+    """The round kernels read and write the (W, D) planes where they lie:
+    a pad to a tile multiple, or a copy around a kernel, would show as
+    temp bytes (at MAIN_D a padded plane set does not fit the chip)."""
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
 def test_main_phase_dim():
@@ -65,8 +75,15 @@ def test_main_phase_dim():
 
 def test_ota_round_stats(one_chip):
     planes = [_spec(one_chip, (MAIN_W, MAIN_D))] * 5
-    _assert_mosaic(
-        lambda *p: ota_round.ota_round_stats(*p, 0.5), *planes)
+    _assert_no_plane_copy(_assert_mosaic(
+        lambda *p: ota_round.ota_round_stats(*p, 0.5), *planes))
+
+
+def test_ota_demodulate_dyn(one_chip):
+    plane = _spec(one_chip, (MAIN_D,))
+    _assert_no_plane_copy(_assert_mosaic(
+        lambda y, z, p2, ia: ota.ota_demodulate_dyn(y, z, p2, ia),
+        plane, plane, plane, _spec(one_chip, ())))
 
 
 def test_ota_round_theta_fused_w256(one_chip):
@@ -122,9 +139,7 @@ def test_population_step(one_chip):
 
 def test_admm_dual_update(one_chip):
     plane = _spec(one_chip, (MAIN_W, MAIN_D))
-    compiled = jax.jit(
+    _assert_no_plane_copy(_assert_mosaic(
         lambda lre, lim, hre, him, t, T: admm_update.admm_dual_update(
             lre, lim, hre, him, t, T, 0.5),
-        donate_argnums=(0, 1)).lower(
-            *[plane] * 5, _spec(one_chip, (MAIN_D,))).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+        *[plane] * 5, _spec(one_chip, (MAIN_D,)), donate=(0, 1)))
