@@ -52,9 +52,10 @@ def offsets(leaves: Sequence[Tuple[str, tuple, object]]
     return out, off
 
 
-def param_count(config) -> int:
+def param_count(config, root=None) -> int:
     from harness import spec
-    return offsets(spec.load_model(config).leaves(config))[1]
+    model = spec.load_model(config, root or spec.ROOT)
+    return offsets(model.leaves(config))[1]
 
 
 def leaf_value(key: jax.Array, path: str, shape: Tuple[int, ...],

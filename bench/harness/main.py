@@ -97,8 +97,9 @@ class _Compiles:
 def prepare(cell: spec.Cell, devices, wrap_step: Optional[Callable] = None):
     """The program's system for the cell (``bench/systems``, named by the
     traffic file) and its compiled step."""
-    system = spec.load_system(cell.traffic).System(
-        cell.config, cell.traffic, devices, wrap_step=wrap_step)
+    system = spec.load_system(cell.traffic, cell.root).System(
+        cell.config, cell.traffic, devices, wrap_step=wrap_step,
+        root=cell.root)
     return system, system.compile()
 
 
@@ -126,10 +127,26 @@ def reference_readings(cell: spec.Cell, prog: dict, seed: int, devices,
     """The reference's first rounds from the seed (``bench/references``,
     named by the traffic file), and the numbers that compare ``prog`` (the
     program's, or the control's) with them."""
-    ref = spec.load_reference(cell.traffic).run(
+    ref = spec.load_reference(cell.traffic, cell.root).run(
         cell.config, cell.traffic, seed, CHECK_ROUNDS, devices=devices,
-        theta1_program=prog.get("Theta1"), **control)
+        theta1_program=prog.get("Theta1"), root=cell.root, **control)
     return ref, compare.readings(prog, ref, cell.limits)
+
+
+def per_layer_metrics(cell: spec.Cell, ctx: Context) -> Dict[str, Any]:
+    """The cell's per-layer metrics from their readers; one that reads
+    nothing is left out, but a listed one fails the run."""
+    metrics = {}
+    for m_ in cell.per_layer:
+        v = spec.load_reader(m_["name"], cell.root).read(ctx)
+        if v is None and "workloads" in m_:
+            # the metric's list names this cell, or the cell's own file
+            # names the metric, as one it finds work in
+            raise RuntimeError(f"{m_['name']} read nothing in {cell.name}, "
+                               "which it lists")
+        if v is not None:
+            metrics[m_["name"]] = {"value": v, "unit": m_["unit"]}
+    return metrics
 
 
 def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
@@ -177,8 +194,9 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     ctx = Context(workload=cell.name, config=cell.config, traffic=tr,
                   chips=len(devices),
                   peaks=device.peaks(dev["kind"]) if trace else {},
-                  model=spec.load_model(cell.config),
-                  n_params=inputs.param_count(cell.config), rounds=n,
+                  model=spec.load_model(cell.config, cell.root),
+                  n_params=inputs.param_count(cell.config, cell.root),
+                  rounds=n,
                   window_s=window_s, program_text=program_text)
     breakdown = None
     if trace:
@@ -193,22 +211,13 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
         breakdown = {"device_ops": trmod.top_ops(ctx.trace),
                      "idle_gaps": trmod.idle_gaps(ctx.trace)}
 
-    metrics = {}
     if trace:
-        for m_ in cell.per_layer:
-            v = spec.load_reader(m_["name"]).read(ctx)
-            if v is None and "workloads" in m_:
-                # the metric names this cell as one it finds work in
-                raise RuntimeError(f"{m_['name']} read nothing in "
-                                   f"{cell.name}, which it lists")
-            if v is not None:
-                metrics[m_["name"]] = {"value": v, "unit": m_["unit"]}
+        metrics = per_layer_metrics(cell, ctx)
     else:
         e2e = {"round_s": window_s / n, "setup_s": setup_s,
                "peak_hbm_gb": dev["memory_peak_bytes"] / 1e9}
-        for m_ in cell.end_to_end:
-            metrics[m_["name"]] = {"value": e2e[m_["name"]],
-                                   "unit": m_["unit"]}
+        metrics = {m_["name"]: {"value": e2e[m_["name"]], "unit": m_["unit"]}
+                   for m_ in cell.end_to_end}
 
     # the check: free the program's state, then run the reference
     del state, step, system, m, batches, keys

@@ -17,6 +17,14 @@ import jax.numpy as jnp
 
 HIGHEST = jax.lax.Precision.HIGHEST
 
+#: configuration keys and the program's ``ModelConfig`` fields they set;
+#: the registry's entry for the configuration's ``arch`` gives the rest
+PROGRAM_FIELDS = {"num_hidden_layers": "n_layers", "vocab_size": "vocab_size",
+                  "hidden_size": "d_model", "intermediate_size": "d_ff",
+                  "num_attention_heads": "n_heads",
+                  "num_key_value_heads": "n_kv_heads", "head_dim": "head_dim",
+                  "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps"}
+
 
 def check(config) -> None:
     """The configurations this model describes."""

@@ -48,8 +48,9 @@ from harness import inputs, spec
 def run(config, traffic, seed: int, n_rounds: int = 3,
         store=None, operand_dtype=None, devices=None,
         theta1_program: Optional[Dict[str, object]] = None,
-        keep_theta1: bool = False) -> dict:
-    """The first ``n_rounds`` rounds from ``seed``.
+        keep_theta1: bool = False, root: str = spec.ROOT) -> dict:
+    """The first ``n_rounds`` rounds from ``seed``, with the plain model
+    found under ``root``.
 
     Returns per-round ``losses`` and ``inv_alpha``, and per-leaf norms:
     ``dtheta1`` of the workers' weights' change in round 1, ``dTheta3`` of
@@ -68,7 +69,7 @@ def run(config, traffic, seed: int, n_rounds: int = 3,
     if n_rounds >= traffic["coherence_iters"]:
         raise ValueError("the reference follows rounds within the first "
                          "coherence block (no fading redraw)")
-    model = spec.load_model(config)
+    model = spec.load_model(config, root)
     lv = model.leaves(config)
     offs, D = inputs.offsets(lv)
     store = jnp.dtype(store or config["torch_dtype"])

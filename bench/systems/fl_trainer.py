@@ -21,13 +21,6 @@ import jax.numpy as jnp
 
 from harness import inputs, spec
 
-#: configuration keys and the registry fields they set
-_FIELDS = {"num_hidden_layers": "n_layers", "vocab_size": "vocab_size",
-           "hidden_size": "d_model", "intermediate_size": "d_ff",
-           "num_attention_heads": "n_heads",
-           "num_key_value_heads": "n_kv_heads", "head_dim": "head_dim",
-           "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps"}
-
 
 def import_program():
     src = os.path.join(spec.ROOT, "src")
@@ -49,25 +42,40 @@ def _kernels(on: bool):
             os.environ["REPRO_USE_PALLAS"] = old
 
 
-def model_config(config):
-    """The registry's ``config['arch']`` with every size the file states
-    taken from the file; the architecture's other choices (family, MLP,
-    head) are the registry's."""
+def model_config(config, root: str = spec.ROOT):
+    """The registry's ``config['arch']`` with each field that the plain
+    model's ``PROGRAM_FIELDS`` maps a configuration key to taken from the
+    file; every other field (family, MLP, head, and any size the map does
+    not name) is the registry's."""
     import_program()
+    from repro.models.config import ModelConfig
     from repro.models.registry import get_config
-    spec.load_model(config).check(config)
-    return dataclasses.replace(get_config(config["arch"]), **{
-        field: config[key] for key, field in _FIELDS.items()})
+    model = spec.load_model(config, root)
+    where = f"bench/models/{config['model_type']}.py"
+    known = {f.name for f in dataclasses.fields(ModelConfig)}
+    fields = {}
+    for key, field in model.PROGRAM_FIELDS.items():
+        if field not in known:
+            raise spec.SpecError(f"{where} maps {key!r} to {field!r}, which "
+                                 "is no field of ModelConfig")
+        if key not in config:
+            raise spec.SpecError(
+                f"the configuration file of {config['name']!r} has no "
+                f"{key!r}, which {where} maps to ModelConfig.{field}")
+        fields[field] = config[key]
+    model.check(config)
+    return dataclasses.replace(get_config(config["arch"]), **fields)
 
 
 class System:
     """The compiled step of one cell, with the state it starts from.
 
     ``wrap_step`` (tests only) wraps the program's ``train_step`` before it
-    is jitted, to plant a fault underneath the harness."""
+    is jitted, to plant a fault underneath the harness; the plain model is
+    found under ``root``."""
 
     def __init__(self, config, traffic, devices,
-                 wrap_step: Optional[Callable] = None):
+                 wrap_step: Optional[Callable] = None, root: str = spec.ROOT):
         import_program()
         from repro.core.admm import AdmmConfig
         from repro.core.channel import ChannelConfig
@@ -75,9 +83,9 @@ class System:
         from repro.train.llm_trainer import FLConfig, make_fl_train
 
         self.config, self.traffic = config, traffic
-        self.ref_model = spec.load_model(config)
+        self.ref_model = spec.load_model(config, root)
         W = traffic["workers"]
-        self.model = build_model(model_config(config))
+        self.model = build_model(model_config(config, root))
         ch = traffic["channel"]
         flcfg = FLConfig(mode=traffic["mode"], n_workers=W,
                          local_steps=traffic["local_steps"],
