@@ -23,7 +23,8 @@ import jax.numpy as jnp
 
 from repro.core import cplx, transport
 from repro.core.admm import AdmmConfig
-from repro.core.channel import ChannelConfig, rayleigh
+from repro.core.channel import (ChannelConfig, gated_redraw, rayleigh,
+                                redraw_like)
 from repro.core.cplx import Complex
 from repro.core.packing import (ShardPackSpec, build_packspec, pack,
                                 pack_cplx, pack_shard_local, scatter_b_chunk,
@@ -90,13 +91,12 @@ def step_channel_tree(key: Array, chan: TreeChannel,
     """Redraw every leaf's fading block at coherence boundaries."""
     age = chan.age + 1
     redraw = age >= ccfg.coherence_iters
-    keys = iter(_leaf_keys(key, chan.h))
 
-    def upd(h_leaf: Complex) -> Complex:
-        fresh = rayleigh(next(keys), h_leaf.re.shape)
-        return cplx.cwhere(redraw, fresh, h_leaf)
+    def draw(key: Array, h: PyTree) -> PyTree:
+        keys = iter(_leaf_keys(key, h))
+        return _zmap(lambda leaf: rayleigh(next(keys), leaf.re.shape), h)
 
-    h = _zmap(upd, chan.h)
+    h = gated_redraw(redraw, draw, key, chan.h)
     new_age = jnp.where(redraw, jnp.zeros((), jnp.int32), age)
     return TreeChannel(h=h, age=new_age), redraw
 
@@ -157,11 +157,11 @@ def init_channel_packed(key: Array, n_workers: int, d: int) -> TreeChannel:
 @layer("chan_step")
 def step_channel_packed(key: Array, chan: TreeChannel,
                         ccfg: ChannelConfig) -> Tuple[TreeChannel, Array]:
-    """Coherence-boundary redraw of a packed fading buffer (one draw)."""
+    """Coherence-boundary redraw of a packed fading buffer (one draw, made
+    only on the boundary)."""
     age = chan.age + 1
     redraw = age >= ccfg.coherence_iters
-    fresh = rayleigh(key, chan.h.re.shape)
-    h = cplx.cwhere(redraw, fresh, chan.h)
+    h = gated_redraw(redraw, redraw_like, key, chan.h)
     new_age = jnp.where(redraw, jnp.zeros((), jnp.int32), age)
     return TreeChannel(h=h, age=new_age), redraw
 

@@ -1,14 +1,15 @@
 """Pallas TPU kernels for the ``repro.phy`` wireless scenario engine.
 
-Two per-round primitives run at packed ``(W, D)`` scale every round once a
-scenario is active, so both get the same one-HBM-pass treatment as the OTA
-transport kernels (``kernels/ota.py``):
+Two primitives run at packed ``(W, D)`` scale once a scenario is active
+(the fading step on each coherence boundary, the receive every round), so
+both get the same one-HBM-pass treatment as the OTA transport kernels
+(``kernels/ota.py``):
 
 * :func:`fading_step` — the Gauss–Markov (AR(1)) small-scale fading
-  recurrence ``h' = rho·h + sqrt(1−rho²)·w`` applied at coherence
-  boundaries (``redraw`` gate), fused over the four input planes
-  (h_re, h_im, w_re, w_im) in a single kernel instead of the ~6 elementwise
-  HLOs XLA would schedule (2 muls + 2 adds + 2 selects per plane pair).
+  recurrence ``h' = rho·h + sqrt(1−rho²)·w``, run on coherence boundaries
+  only (the caller gates it), fused over the four input planes
+  (h_re, h_im, w_re, w_im) in a single kernel instead of the 4 elementwise
+  HLOs XLA would schedule (2 muls + 2 adds per plane pair).
 
 * :func:`ota_receive_masked` — the participation-aware receive chain:
   masked workers are zeroed *inside* the kernel (``where``, so NaN/Inf
@@ -45,38 +46,33 @@ def _scalar_spec(n: int = 1):
 
 def _fading_step_kernel(p_ref, hre_ref, him_ref, wre_ref, wim_ref,
                         ore_ref, oim_ref):
-    rho, scale, redraw = p_ref[0], p_ref[1], p_ref[2]
-    upd = redraw != 0.0
-    ore_ref[...] = jnp.where(upd, rho * hre_ref[...] + scale * wre_ref[...],
-                             hre_ref[...])
-    oim_ref[...] = jnp.where(upd, rho * him_ref[...] + scale * wim_ref[...],
-                             him_ref[...])
+    rho, scale = p_ref[0], p_ref[1]
+    ore_ref[...] = rho * hre_ref[...] + scale * wre_ref[...]
+    oim_ref[...] = rho * him_ref[...] + scale * wim_ref[...]
 
 
 def fading_step(h_re: Array, h_im: Array, w_re: Array, w_im: Array,
-                rho: float, scale: float, redraw: Array | bool,
+                rho: float, scale: float,
                 *, block_rows: Optional[int] = None,
                 interpret: bool = False) -> Tuple[Array, Array]:
-    """Fused AR(1) fading update over flat planes.
+    """Fused AR(1) fading update over flat planes: ``h' = rho·h + scale·w``.
 
-    ``h' = rho·h + scale·w`` where ``redraw`` gates the update (False keeps
-    the block — the inter-boundary hold of block fading).  ``rho``/``scale``
-    are trace-time floats; ``redraw`` is a traced bool scalar (the coherence
-    counter lives in jit-compiled round loops).
+    ``rho``/``scale`` are trace-time floats.  The coherence gate is the
+    caller's (``phy.fading.gauss_markov_step`` runs this kernel only on a
+    boundary, through ``core.channel.gated_redraw``).
     """
     block_rows = _block_rows(block_rows, 6)
     n = h_re.size
     rows = _rows_for(n)
     args = [_pad_2d(a.astype(jnp.float32), rows)
             for a in (h_re, h_im, w_re, w_im)]
-    params = jnp.stack([
-        jnp.asarray(rho, jnp.float32), jnp.asarray(scale, jnp.float32),
-        jnp.asarray(redraw, jnp.float32)])
+    params = jnp.stack([jnp.asarray(rho, jnp.float32),
+                        jnp.asarray(scale, jnp.float32)])
     grid, _, spec = _grid_spec(0, rows, block_rows)
     ore, oim = pl.pallas_call(
         _fading_step_kernel,
         grid=grid,
-        in_specs=[_scalar_spec(3)] + [spec] * 4,
+        in_specs=[_scalar_spec(2)] + [spec] * 4,
         out_specs=[spec, spec],
         out_shape=[jax.ShapeDtypeStruct((rows, LANE), jnp.float32)] * 2,
         interpret=interpret,

@@ -49,7 +49,8 @@ def _population_step_kernel(p_ref,
     step, d0, dnorm = p_ref[3], p_ref[4], p_ref[5]
     pexp, sh_redraw = p_ref[6], p_ref[7]
 
-    # --- AR(1) fading at coherence boundaries (== phy_channel.fading_step)
+    # --- AR(1) fading at coherence boundaries (phy_channel.fading_step
+    # under phy.fading's gate)
     upd = redraw != 0.0
     ohre_ref[...] = jnp.where(upd, rho * hre_ref[...] + scale * wre_ref[...],
                               hre_ref[...])

@@ -17,6 +17,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.checkpoint import save
 from repro.core.admm import AdmmConfig
@@ -167,9 +168,12 @@ def main() -> None:
                          "compile, execute, and each round's batch, "
                          "dispatch, readback, log and checkpoint (also "
                          "trace annotations inside a 'round' trace step), "
-                         "compilations after the first dispatch, and the "
+                         "compilations after the first dispatch, the "
                          "OTA kernels' grid steps and padded columns per "
-                         "round (ota_grid_steps, ota_pad_cols)")
+                         "round (ota_grid_steps, ota_pad_cols), and with "
+                         "telemetry on the rounds whose fading step "
+                         "redrew the channel (chan_redraws, the sum of "
+                         "obs/chan_redraw)")
     args = ap.parse_args()
     enable_compile_cache()
 
@@ -380,6 +384,8 @@ def main() -> None:
         from repro.obs.profiling import trace_session
         trace_ctx = trace_session(os.path.join(args.run_dir, "trace"))
 
+    #: rounds whose fading step redrew the channel (telemetry on)
+    chan_redraws = 0
     t0 = time.time()
     with trace_ctx:
         if args.driver == "scan":
@@ -424,6 +430,8 @@ def main() -> None:
                             with span("readback"):
                                 # host sync: timing is real
                                 ms = jax.device_get(ms)
+                            chan_redraws += int(np.sum(
+                                ms.get("obs/chan_redraw", 0.0)))
                     with span("log"):
                         if sink is not None:
                             # EVERY round of the block goes to the
@@ -456,6 +464,8 @@ def main() -> None:
                         if timer is not None:     # logged or profiled
                             with span("readback"):
                                 metrics = jax.device_get(metrics)
+                            chan_redraws += int(np.sum(
+                                metrics.get("obs/chan_redraw", 0.0)))
                     with span("log"):
                         if sink is not None:
                             sink.log_round(r, metrics)
@@ -473,9 +483,12 @@ def main() -> None:
         summ = timer.summary()
         recompiles = timer.compiles_after_first
         # per round: the grid steps of the OTA kernels' column grids, and
-        # the columns their planes were padded by in HBM
+        # the columns their planes were padded by in HBM; over the run, the
+        # rounds whose fading step redrew the channel
         grid = {"ota_grid_steps": sum(l["steps"] for l in step_launches),
                 "ota_pad_cols": sum(l["pad_cols"] for l in step_launches)}
+        if telemetry_on:
+            grid["chan_redraws"] = chan_redraws
         if args.run_dir:
             with open(os.path.join(args.run_dir, "profile.json"), "w") as f:
                 json.dump({"spans": summ, "series": timer.series,

@@ -61,6 +61,9 @@ present when a ``GuardConfig`` is active):
                          a (W,) VECTOR leaf (sinks store it as a list)
     ``obs/active_workers``  number of workers transmitting this round
     ``obs/theta_update_norm``  l2 norm of the committed Theta update
+    ``obs/chan_redraw``  1.0 on a round whose fading step crossed a
+                         coherence boundary (redrew), else 0.0 (the
+                         LLM trainers, ``train.llm_trainer``)
     ``obs/cohort_size``  workers sampled this round (population/cohort
                          sampling active — ``core.cohort``)
     ``obs/population_sampled_frac``  cohort / population

@@ -18,7 +18,8 @@ applied at coherence boundaries (bit-parity pinned in ``tests/test_phy.py``).
 All steps are pure ``(key, h) -> h`` functions over packed ``(W, D)``
 :class:`~repro.core.cplx.Complex` planes, scan/jit/shard_map-safe, with a
 fused Pallas kernel backend (``kernels/phy_channel.fading_step``: one HBM
-pass per round) behind the same ``backend=`` dispatch as the transport.
+pass per boundary round) behind the same ``backend=`` dispatch as the
+transport.
 """
 from __future__ import annotations
 
@@ -28,8 +29,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core import cplx
-from repro.core.channel import rayleigh
+from repro.core.channel import gated_redraw, rayleigh
 from repro.core.cplx import Complex
 from repro.core.transport import _interpret, resolve_backend
 
@@ -78,25 +78,30 @@ def gauss_markov_step(key: Array, h: Complex, rho: float,
                       backend: Optional[str] = None) -> Complex:
     """One AR(1) fading update, gated by ``redraw`` (coherence boundary).
 
-    ``rho`` is a trace-time float.  ``rho == 0.0`` takes the *exact*
-    block-fading arithmetic (`cwhere(redraw, fresh, h)`) so the legacy
-    channel is reproduced bitwise, not merely to rounding.
+    ``rho`` is a trace-time float.  The innovations draw and the mix run
+    only on the boundary (:func:`~repro.core.channel.gated_redraw`); off it
+    ``h`` passes through.  ``rho == 0.0`` returns the draw itself, so the
+    legacy block-fading channel is reproduced bitwise, not merely to
+    rounding.
     """
-    w = rayleigh(key, h.re.shape, h.re.dtype)
-    if resolve_backend(backend) == "pallas":
-        from repro.kernels import phy_channel as _k
-        shape = h.re.shape
-        ore, oim = _k.fading_step(
-            h.re.reshape(-1), h.im.reshape(-1),
-            w.re.reshape(-1), w.im.reshape(-1),
-            float(rho), innovation_scale(rho), redraw,
-            interpret=_interpret())
-        return Complex(ore.reshape(shape), oim.reshape(shape))
-    if float(rho) == 0.0:
-        return cplx.cwhere(redraw, w, h)
-    s = innovation_scale(rho)
-    nxt = Complex(rho * h.re + s * w.re, rho * h.im + s * w.im)
-    return cplx.cwhere(redraw, nxt, h)
+    pallas = resolve_backend(backend) == "pallas"
+
+    def mix(key: Array, h: Complex) -> Complex:
+        w = rayleigh(key, h.re.shape, h.re.dtype)
+        if pallas:
+            from repro.kernels import phy_channel as _k
+            shape = h.re.shape
+            ore, oim = _k.fading_step(
+                h.re.reshape(-1), h.im.reshape(-1),
+                w.re.reshape(-1), w.im.reshape(-1),
+                float(rho), innovation_scale(rho), interpret=_interpret())
+            return Complex(ore.reshape(shape), oim.reshape(shape))
+        if float(rho) == 0.0:
+            return w
+        s = innovation_scale(rho)
+        return Complex(rho * h.re + s * w.re, rho * h.im + s * w.im)
+
+    return gated_redraw(redraw, mix, key, h)
 
 
 def correlated_step(key: Array, h: Complex, age: Array, rho: float,
@@ -106,8 +111,10 @@ def correlated_step(key: Array, h: Complex, age: Array, rho: float,
     """Advance one round: AR(1)-mix the fading at coherence boundaries.
 
     Returns ``(h_new, age_new, redraw)``.  With ``rho=0`` this IS the legacy
-    ``core.channel.step_channel_packed`` (same PRNG consumption: the full
-    ``key`` feeds one :func:`~repro.core.channel.rayleigh` draw).
+    ``core.tree_ota.step_channel_packed``: on a boundary the full ``key``
+    feeds one :func:`~repro.core.channel.rayleigh` draw, and off it no draw
+    is made at all; both gate through
+    :func:`~repro.core.channel.gated_redraw`.
     """
     age = age + 1
     redraw = age >= coherence_iters

@@ -331,16 +331,17 @@ def make_replicated(model: Model, flcfg: FLConfig, acfg: AdmmConfig,
                 unpack_tree = lambda buf: unpack_cplx(spec, buf)
         if scn is not None:
             chan = scn.step(kc, state.chan)       # PhyState, (N, D)-packed
+            redraw = chan.age == 0                # the age resets on a redraw
             h_pack = _phys_h_tx(chan)
             if scn.truncating:
                 mask, Theta_prev = chan.mask, state.Theta
             if scn.imperfect_csi:
                 h_tx_p = chan.h_hat
         elif packed:
-            chan, _changed = step_channel_packed(kc, state.chan, ccfg)
+            chan, redraw = step_channel_packed(kc, state.chan, ccfg)
             h_pack = chan.h
         else:
-            chan, _changed = step_channel_tree(kc, state.chan, ccfg)
+            chan, redraw = step_channel_tree(kc, state.chan, ccfg)
             lam_tree, h_tree = state.lam, chan.h
         theta_run, opt_run = state.theta, state.opt
         if packed:
@@ -439,6 +440,8 @@ def make_replicated(model: Model, flcfg: FLConfig, acfg: AdmmConfig,
                      for n, o in zip(jax.tree.leaves(Theta_new),
                                      jax.tree.leaves(state.Theta)))
             m["obs/theta_update_norm"] = jnp.sqrt(sq)
+        if tel is not None:
+            m["obs/chan_redraw"] = redraw.astype(jnp.float32)
         new_state = TreeFLState(theta=theta, lam=lam_new, Theta=Theta_new,
                                 chan=chan, opt=opt_state,
                                 step=state.step + 1, flt=flt_new)
@@ -713,12 +716,13 @@ def make_sketched(model: Model, flcfg: FLConfig, acfg: AdmmConfig,
         mask = h_tx_p = None
         if scn is not None:
             chan = scn.step(kc, state.chan)     # PhyState over (W, d_s)
+            redraw = chan.age == 0              # the age resets on a redraw
             if scn.truncating:
                 mask = chan.mask
             if scn.imperfect_csi:
                 h_tx_p = chan.h_hat
         else:
-            chan, _ = step_channel_packed(kc, state.chan, ccfg)
+            chan, redraw = step_channel_packed(kc, state.chan, ccfg)
 
         faults_arg = None
         fmetrics = {}
@@ -774,6 +778,7 @@ def make_sketched(model: Model, flcfg: FLConfig, acfg: AdmmConfig,
             sq = sum(jnp.sum(jnp.square(l.astype(jnp.float32)))
                      for l in jax.tree.leaves(g_delta))
             metrics["obs/theta_update_norm"] = flcfg.sketch_lr * jnp.sqrt(sq)
+            metrics["obs/chan_redraw"] = redraw.astype(jnp.float32)
         return new_state, metrics
 
     return init_fn, train_step

@@ -16,8 +16,9 @@
 * Layer scopes (``repro.obs.LAYERS``): ``layer`` refuses a name outside
   the vocabulary and nests as it says; the trainer's compiled step carries
   the scopes in its HLO metadata.  The launcher's ``--profile`` names each
-  round's host phases in the trace, counts recompilations, and counts the
-  OTA kernels' grid steps and padded columns per round.
+  round's host phases in the trace, counts recompilations, counts the
+  OTA kernels' grid steps and padded columns per round, and with telemetry
+  on counts the rounds whose fading step redrew the channel.
 """
 import json
 import math
@@ -458,6 +459,24 @@ def test_launcher_profile_counts_ota_grid_steps(tmp_path):
     prof = json.load(open(os.path.join(rd, "profile.json")))
     assert (prof["ota_grid_steps"], prof["ota_pad_cols"]) == (steps, 0)
     assert f"ota_grid_steps={steps}, ota_pad_cols=0" in p.stdout
+
+
+@pytest.mark.parametrize("driver", ["loop", "scan"])
+def test_launcher_profile_counts_chan_redraws(tmp_path, driver):
+    """--profile with telemetry on: ``chan_redraws`` sums obs/chan_redraw
+    over the run (4 rounds at coherence 2 redraw on rounds 2 and 4); with
+    telemetry off the count is not reported."""
+    common = ("--rounds", "4", "--log-every", "2", "--coherence", "2",
+              "--driver", driver, "--profile")
+    rd = str(tmp_path / "run")
+    p = _launch(tmp_path, *common, "--run-dir", rd)
+    assert p.returncode == 0, p.stderr[-2000:]
+    assert json.load(open(os.path.join(rd, "profile.json")))[
+        "chan_redraws"] == 2
+    assert "chan_redraws=2" in p.stdout
+    p = _launch(tmp_path, *common, "--telemetry", "off")
+    assert p.returncode == 0, p.stderr[-2000:]
+    assert "ota_grid_steps=" in p.stdout and "chan_redraws" not in p.stdout
 
 
 # ---------------------------------------------------------------------------

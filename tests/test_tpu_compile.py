@@ -8,7 +8,10 @@ VMEM than the chip allows.  Each test compiles one kernel at the shapes of
 6,144-row vocabulary slice, W=2, sequence 2,048) and checks that the
 program holds a Mosaic kernel (``tpu_custom_call``); the OTA round's
 kernels also compile at their default column tile, which does not divide D,
-with no temp bytes: no plane is padded or copied.  Nothing runs.
+with no temp bytes: no plane is padded or copied.  The gated fading step
+compiles to a loop (never a ``conditional``, which makes the compiler
+reserve HBM for program overlays) that keeps the donated h planes in place.
+Nothing runs.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and pytest-xdist workers all
@@ -143,3 +146,27 @@ def test_admm_dual_update(one_chip):
         lambda lre, lim, hre, him, t, T: admm_update.admm_dual_update(
             lre, lim, hre, him, t, T, 0.5),
         *[plane] * 5, _spec(one_chip, (MAIN_D,)), donate=(0, 1)))
+
+
+def test_gated_fading_step_keeps_h_in_place(one_chip):
+    """The packed fading step at the main phase's size: the draw sits in a
+    ``while`` loop, no ``conditional`` is compiled, and the donated (W, D)
+    planes are aliased to the output with no copy of either."""
+    import re
+
+    from repro.core.channel import ChannelConfig
+    from repro.core.cplx import Complex
+    from repro.core.tree_ota import TreeChannel, step_channel_packed
+    plane = _spec(one_chip, (MAIN_W, MAIN_D))
+    chan = TreeChannel(h=Complex(plane, plane),
+                       age=_spec(one_chip, (), jnp.int32))
+    ccfg = ChannelConfig(n_workers=MAIN_W, coherence_iters=10)
+    compiled = jax.jit(lambda k, c: step_channel_packed(k, c, ccfg),
+                       donate_argnums=(1,)).lower(
+        _spec(one_chip, (2,), jnp.uint32), chan).compile()
+    text = compiled.as_text()
+    assert " while(" in text and " conditional(" not in text
+    shape = f"f32[{MAIN_W},{MAIN_D}]"
+    assert not re.search(re.escape(shape) + r"\S* copy(-start)?\(", text)
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= 2 * MAIN_W * MAIN_D * 4
